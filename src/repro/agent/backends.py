@@ -8,7 +8,8 @@ with a calibrated error rate matching the paper's measured GPT-hit rates
 metrics) matches Table I per (model x prompting x shot) cell.
 
 ``JaxLLM`` routes ``complete()`` through the real JAX serving engine
-(`repro.serving`) — used in the examples with the dcache-agent-150m model.
+(`repro.serving`) — the dcache-agent-150m decision model in
+``examples/serve_llm.py`` and ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -307,8 +308,8 @@ class SimLLM:
 class JaxLLM:
     """Real decision model: completions generated by the JAX serving engine.
 
-    Constructed lazily from an ``repro.serving.engine.ServingEngine`` plus a
-    byte-level tokenizer; used by examples/serve_agent.py.
+    Wraps a ``repro.serving.engine.ServingEngine`` (byte-level tokenizer);
+    used by ``examples/serve_llm.py`` and ``chip_smoke.py``.
     """
 
     def __init__(self, engine, max_new_tokens: int = 64):

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -46,7 +44,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    pos = pos_ref[0]                                     # () current position
+    pos = pos_ref[pl.program_id(0)]                      # () current position
     j = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     pslot = pos - jax.lax.rem(pos - j + cache_len * 2, cache_len)
     ok = pslot >= 0
@@ -100,25 +98,28 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         _decode_kernel, scale=scale, window=window, chunk=chunk,
         block_k=block_k, n_kv_blocks=nk, cache_len=C)
 
-    out = pl.pallas_call(
-        kernel,
+    # pos (B,) is scalar-prefetched into SMEM whole: the kernel gets it as
+    # its first ref, each index map as a trailing argument
+    q_spec = pl.BlockSpec((1, 1, group, d), lambda b, h, ki, _: (b, h, 0, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_k, d),
+                           lambda b, h, ki, _: (b, h, ki, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, Hkv, nk),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, group, d), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, ki: (b, h, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, d), lambda b, h, ki: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, d), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((group, LANES), jnp.float32),
             pltpu.VMEM((group, LANES), jnp.float32),
             pltpu.VMEM((group, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(pos, qg, k, v)
+    )(pos.astype(jnp.int32), qg, k, v)
     return out.reshape(B, Hq, d)

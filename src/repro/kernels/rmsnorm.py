@@ -13,8 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -46,7 +44,7 @@ def rmsnorm(x: jax.Array, gain: jax.Array, *, eps: float = 1e-5,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, gain)

@@ -20,32 +20,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_final_ref,
-                state_ref, *, chunk: int, n_chunks: int):
+                state_ref, x_ref, y_ref, *, chunk: int, n_chunks: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    u = u_ref[0].astype(jnp.float32)                      # (hd,)
+    hd = state_ref.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 1))
+
+    def col(row):
+        """(1, hd) row -> (hd, 1) column, with 2-D ops the TPU lowers."""
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+    u = col(u_ref[0].astype(jnp.float32))                 # (hd, 1)
+
+    # the chunk is upcast once into fp32 scratch: single-row loads and
+    # stores at a dynamic offset need an unpacked (32-bit) layout
+    for i, ref in enumerate((r_ref, k_ref, v_ref, w_ref)):
+        x_ref[i] = ref[0, 0].astype(jnp.float32)
 
     def step(t, _):
-        rt = r_ref[0, 0, t].astype(jnp.float32)           # (hd,)
-        kt = k_ref[0, 0, t].astype(jnp.float32)
-        vt = v_ref[0, 0, t].astype(jnp.float32)
-        wt = w_ref[0, 0, t].astype(jnp.float32)
+        rt, kt, vt, wt = (x_ref[i, pl.ds(t, 1), :] for i in range(4))
         s = state_ref[...]                                # (hd, hd) fp32
-        kv = kt[:, None] * vt[None, :]                    # rank-1 outer
-        y = jnp.einsum("i,ij->j", rt, s + u[:, None] * kv)
-        state_ref[...] = wt[:, None] * s + kv
-        o_ref[0, 0, t] = y.astype(o_ref.dtype)
+        kv = col(kt) * vt                                 # rank-1 outer
+        y = jnp.sum(col(rt) * (s + u * kv), axis=0, keepdims=True)
+        state_ref[...] = col(wt) * s + kv
+        y_ref[pl.ds(t, 1), :] = y
         return 0
 
     jax.lax.fori_loop(0, chunk, step, 0)
+    o_ref[0, 0] = y_ref[...].astype(o_ref.dtype)
 
     @pl.when(ci == n_chunks - 1)
     def _final():
@@ -70,7 +79,8 @@ def wkv(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             pl.BlockSpec((1, 1, chunk, hd), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, chunk, hd), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, chunk, hd), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, hd), lambda b, h, c: (h, 0)),
+            # u rides as (H, 1, hd): a (1, hd) tile spans both minor dims
+            pl.BlockSpec((1, 1, hd), lambda b, h, c: (h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, chunk, hd), lambda b, h, c: (b, h, c, 0)),
@@ -80,9 +90,11 @@ def wkv(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             jax.ShapeDtypeStruct((B, H, S, hd), r.dtype),
             jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32),
+                        pltpu.VMEM((4, chunk, hd), jnp.float32),
+                        pltpu.VMEM((chunk, hd), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, u.reshape(H, 1, hd))
     return y, s_final

@@ -20,6 +20,7 @@ import jax
 from repro.configs import ALL_IDS, get_config
 from repro.distributed.checkpoint import Checkpointer
 from repro.distributed.fault_tolerance import HeartbeatMonitor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.common import Init, unbox
 from repro.models.model import init_model
 from repro.training.data import Prefetcher, TokenStream
@@ -41,6 +42,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.preset == "smoke":
         cfg = cfg.reduced()

@@ -62,12 +62,11 @@ def compressed_psum(g: jax.Array, axis_name: str) -> jax.Array:
 
 def make_compressed_allreduce(mesh, axis_name: str = "data"):
     """shard_map'd gradient mean over the DP axis with int8 compression."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     @functools.partial(
-        shard_map, mesh=mesh,
-        in_specs=P(axis_name), out_specs=P(axis_name), check_rep=False)
+        jax.shard_map, mesh=mesh,
+        in_specs=P(axis_name), out_specs=P(axis_name), check_vma=False)
     def allreduce(g):
         return compressed_psum(g, axis_name)
 
