@@ -42,7 +42,7 @@ from repro.agent.geollm.datastore import GeoDataStore  # noqa: E402
 from repro.agent.geollm.workload import WorkloadSampler  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.configs.base import ModelConfig  # noqa: E402
-from repro.core import prompts  # noqa: E402
+from repro.core import profiling, prompts  # noqa: E402
 from repro.core.admission import TinyLFU  # noqa: E402
 from repro.core.cache import DataCache  # noqa: E402
 from repro.core.controller import LLMController  # noqa: E402
@@ -86,20 +86,6 @@ def require(ok, msg) -> None:
     """``assert`` that ``python -O`` cannot strip."""
     if not ok:
         raise SmokeFailure(msg)
-
-
-class CompileClock:
-    """Backend compile seconds and count, from JAX's monitoring events."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.count = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.count += 1
 
 
 # -- 1. device ---------------------------------------------------------------
@@ -303,7 +289,7 @@ def main(argv=None) -> None:
     log("device", f"platform={device['platform']} kind={device['kind']} "
                   f"count={device['count']}")
     log("device", f"compile cache: {enable_compile_cache()}")
-    clock = CompileClock()
+    profiling.count_compiles()
 
     cfg = serve_config(ARCH, "full")
     sizes = PRESETS["full"]
@@ -323,12 +309,14 @@ def main(argv=None) -> None:
                    f"max_batch={eng.max_batch} max_len={eng.max_len}; "
                    f"init {time.perf_counter() - t0:.2f} s")
     texts = decision_prompts(args.seed)
-    c0 = (clock.seconds, clock.count)
+    c0 = profiling.snapshot()
     st = check_serving(eng, texts, max_new_tokens=32, window=16)
+    c = profiling.delta(c0, profiling.snapshot())
     log("serving", f"ok: {st['requests']} requests finished, longest "
                    f"prompt {st['prompt_bytes_max']} bytes; first pass "
-                   f"{st['first_pass_s']:.2f} s with {clock.count - c0[1]} "
-                   f"compiles taking {clock.seconds - c0[0]:.2f} s; "
+                   f"{st['first_pass_s']:.2f} s with "
+                   f"{c.get('jax.compiles', 0):.0f} compiles taking "
+                   f"{c.get('jax.compile_s', 0.0):.2f} s; "
                    f"decode step {st['decode_step_ms']:.2f} ms at batch "
                    f"{eng.max_batch} (sanity figures, not metrics)")
 
@@ -345,7 +333,9 @@ def main(argv=None) -> None:
                        f"agrees on {cs['argmax_matches']}/{cs['steps']}")
 
     mem = jax.devices()[0].memory_stats() or {}
-    log("device", f"{clock.count} compiles, {clock.seconds:.2f} s in the "
+    c = profiling.snapshot()
+    log("device", f"{c.get('jax.compiles', 0):.0f} compiles, "
+                  f"{c.get('jax.compile_s', 0.0):.2f} s in the "
                   f"backend compiler; peak device memory "
                   f"{mem.get('peak_bytes_in_use', 0) / 2**30:.2f} GiB")
     print(json.dumps({"ok": True, "device": device}), flush=True)

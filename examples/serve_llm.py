@@ -58,7 +58,8 @@ def main():
     s = eng.stats()
     print(f"\nserved {s['finished']} requests in {time.time()-t0:.1f}s "
           f"({s['throughput_tok_s']:.1f} tok/s, "
-          f"ttft {s['mean_ttft_s']*1e3:.0f} ms)")
+          f"ttft p50 {s['ttft_from_submit_p50_s']*1e3:.0f} ms, "
+          f"{s['host_reads_per_step']:.1f} host reads/step)")
     for r in reqs[:3]:
         print(f"  [{r.rid}] -> {eng.tok.decode(r.out_ids)!r}")
 
