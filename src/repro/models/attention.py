@@ -66,15 +66,16 @@ def _project_qkv(p: Dict, cfg: ModelConfig, x: jax.Array,
 
 
 def _mask(qpos: jax.Array, kpos: jax.Array, cfg: ModelConfig,
-          causal: bool) -> jax.Array:
-    """(len(qpos), len(kpos)) additive mask in fp32."""
+          causal: bool, local: bool = True) -> jax.Array:
+    """(len(qpos), len(kpos)) additive mask in fp32. The window and chunk
+    bound attention within one sequence (``local``), not cross-attention."""
     qp, kp = qpos[:, None], kpos[None, :]
     ok = jnp.ones(qp.shape[:1] + kp.shape[1:], dtype=bool)
     if causal:
         ok &= kp <= qp
-    if cfg.sliding_window is not None:
+    if local and cfg.sliding_window is not None:
         ok &= (qp - kp) < cfg.sliding_window
-    if cfg.attn_chunk is not None:
+    if local and cfg.attn_chunk is not None:
         ok &= (qp // cfg.attn_chunk) == (kp // cfg.attn_chunk)
     return jnp.where(ok, 0.0, NEG_INF).astype(jnp.float32)
 
@@ -138,7 +139,7 @@ def attend(p: Dict, cfg: ModelConfig, x: jax.Array, *,
             kp = start + jnp.arange(kv_span, dtype=jnp.int32)
         s = jnp.einsum("bckgh,btkh->bkgct", qi, ks,
                        preferred_element_type=jnp.float32) * scale
-        s = s + _mask(qpi, kp, cfg, causal)[None, None, None]
+        s = s + _mask(qpi, kp, cfg, causal, kv_x is None)[None, None, None]
         w = jax.nn.softmax(s, axis=-1).astype(vs.dtype)
         o = jnp.einsum("bkgct,btkh->bckgh", w, vs)
         return None, o
@@ -185,9 +186,13 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
     """Inverse of quantize_kv; returns (..., KVH*hd) in ``dtype``."""
     kvh = scale.shape[-1]
     hd = q.shape[-1] // kvh
-    xr = q.reshape(q.shape[:-1] + (kvh, hd)).astype(jnp.float32)
-    xr = xr * scale[..., None].astype(jnp.float32)
-    return xr.reshape(q.shape).astype(dtype)
+    # the scales are widened to the codes' (..., KVH*hd) by an exact 0/1
+    # product: splitting the codes into (KVH, hd), or a repeat of the scales,
+    # has the TPU compiler relayout the whole int8 ring
+    widen = jnp.repeat(jnp.eye(kvh, dtype=jnp.float32), hd, axis=-1)
+    s = jnp.einsum("...k,kf->...f", scale.astype(jnp.float32), widen,
+                   precision="highest")
+    return (q.astype(jnp.float32) * s).astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -195,63 +200,76 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def decode_attend(p: Dict, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
-                  k_cache: jax.Array, v_cache: jax.Array,
-                  k_scale: Optional[jax.Array] = None,
-                  v_scale: Optional[jax.Array] = None):
-    """One-token attention against the cache.
+                  ring: Dict[str, jax.Array]):
+    """One-token attention against the ring-buffer cache, which it only reads.
 
-    x: (B,1,D); pos: (B,) tokens generated so far; k/v_cache: (B,C,KV*hd)
-    (ring buffer — token t lives in slot t %% C; int8 when cfg.kv_quant,
-    with per-token-per-head scales). Returns (out, k', v'[, ks', vs'])."""
+    x: (B,1,D); pos: (B,) tokens generated so far; ring: ``k``/``v``
+    (B,C,KV*hd), token t in slot t %% C (int8 when cfg.kv_quant, with
+    per-token-per-head ``k_scale``/``v_scale`` (B,C,KV)). The new token goes
+    to slot pos %% C: that slot's old entry is masked, and the new key and
+    value join the softmax beside the ring. Returns (out, rows): ``rows``
+    holds each ring leaf's new entry (B, ...), for the caller to write at
+    slot pos %% C."""
     B, _, _ = x.shape
-    C = k_cache.shape[1]
+    C = ring["k"].shape[1]
     hd, kvh = cfg.head_dim_, cfg.n_kv_heads
     q, k_new, v_new = _project_qkv(p, cfg, x)
     q = rope(q.reshape(B, 1, -1, hd), pos[:, None], cfg.rope_theta).reshape(q.shape)
     k_new = rope(k_new, pos[:, None], cfg.rope_theta)
 
-    slot = (pos % C).astype(jnp.int32)
-    bidx = jnp.arange(B)
-    kn = k_new[:, 0].reshape(B, -1)
-    vn = v_new[:, 0].reshape(B, -1)
+    rows = {"k": k_new[:, 0].reshape(B, -1), "v": v_new[:, 0].reshape(B, -1)}
     if cfg.kv_quant:
-        kn_q, kn_s = quantize_kv(kn, kvh)
-        vn_q, vn_s = quantize_kv(vn, kvh)
-        k_cache = k_cache.at[bidx, slot].set(kn_q)
-        v_cache = v_cache.at[bidx, slot].set(vn_q)
-        k_scale = k_scale.at[bidx, slot].set(kn_s)
-        v_scale = v_scale.at[bidx, slot].set(vn_s)
-        kc = dequantize_kv(k_cache, k_scale, x.dtype).reshape(B, C, kvh, hd)
-        vc = dequantize_kv(v_cache, v_scale, x.dtype).reshape(B, C, kvh, hd)
+        rows["k"], rows["k_scale"] = quantize_kv(rows["k"], kvh)
+        rows["v"], rows["v_scale"] = quantize_kv(rows["v"], kvh)
+        kc = dequantize_kv(ring["k"], ring["k_scale"], x.dtype)
+        vc = dequantize_kv(ring["v"], ring["v_scale"], x.dtype)
+        kn = dequantize_kv(rows["k"], rows["k_scale"], x.dtype)
+        vn = dequantize_kv(rows["v"], rows["v_scale"], x.dtype)
     else:
-        k_cache = k_cache.at[bidx, slot].set(kn)
-        v_cache = v_cache.at[bidx, slot].set(vn)
-        kc = k_cache.reshape(B, C, kvh, hd)
-        vc = v_cache.reshape(B, C, kvh, hd)
+        kc, vc, kn, vn = ring["k"], ring["v"], rows["k"], rows["v"]
+    kn, vn = kn.reshape(B, kvh, hd), vn.reshape(B, kvh, hd)
 
-    # slot j holds position pslot[j] = pos - ((pos - j) mod C)  (after write,
-    # cache holds positions (pos-C, pos]); valid iff 0 <= pslot <= pos and
-    # within window/chunk of the current position.
+    # once this token is written, slot j holds position
+    # pslot[j] = pos - ((pos - j) mod C), the cache holding (pos-C, pos]; the
+    # ring's slot pos %% C (pslot == pos) still holds pos - C and is masked.
+    # A slot is valid iff 0 <= pslot < pos and within the window/chunk of pos.
     j = jnp.arange(C, dtype=jnp.int32)[None, :]
     pnow = pos[:, None].astype(jnp.int32)
     pslot = pnow - jnp.mod(pnow - j, C)
-    ok = pslot >= 0
+    ok = (pslot >= 0) & (pslot < pnow)
     if cfg.sliding_window is not None:
         ok &= (pnow - pslot) < cfg.sliding_window
     if cfg.attn_chunk is not None:
         ok &= (pslot // cfg.attn_chunk) == (pnow // cfg.attn_chunk)
     mask = jnp.where(ok, 0.0, NEG_INF).astype(jnp.float32)  # (B,C)
 
-    # q from _project_qkv is (B,1,KV,G,hd) -> squeeze the seq dim
-    s = jnp.einsum("bkgh,btkh->bkgt", q[:, 0], kc,
-                   preferred_element_type=jnp.float32) * (hd ** -0.5)
-    s = s + mask[:, None, None, :]
-    w = jax.nn.softmax(s, axis=-1).astype(vc.dtype)
-    o = jnp.einsum("bkgt,btkh->bkgh", w, vc).reshape(B, 1, -1)
-    out = jnp.einsum("bsh,hd->bsd", o, p["wo"])
-    if cfg.kv_quant:
-        return out, k_cache, v_cache, k_scale, v_scale
-    return out, k_cache, v_cache
+    # The ring is read in its stored (C, KV*hd) layout: each query head
+    # (q from _project_qkv is (B,1,KV,G,hd)) becomes a row of KV*hd that is
+    # zero outside its own KV head, and of the (Q, KV*hd) output each head
+    # keeps its own block. Reshaping the ring to (C, KV, hd) instead costs a
+    # relayout of every layer's slab.
+    G = q.shape[3]
+    f32 = jnp.float32
+    scale = hd ** -0.5
+    eye = jnp.eye(kvh, dtype=q.dtype)
+    qf = (q[:, 0, :, :, None, :] * eye[None, :, None, :, None]
+          ).reshape(B, kvh * G, kvh * hd)
+    s = jnp.einsum("btf,bqf->btq", kc, qf, preferred_element_type=f32) * scale
+    s = s + mask[:, :, None]                                       # (B,C,Q)
+    s_new = jnp.einsum("bkgh,bkh->bkg", q[:, 0], kn,
+                       preferred_element_type=f32).reshape(B, 1, -1) * scale
+    # softmax over the ring's C columns and the new token's one
+    m = jnp.maximum(jnp.max(s, axis=1, keepdims=True), s_new)
+    e, e_new = jnp.exp(s - m), jnp.exp(s_new - m)
+    den = jnp.sum(e, axis=1, keepdims=True) + e_new
+    w, w_new = (e / den).astype(vc.dtype), (e_new / den).astype(vc.dtype)
+    of = jnp.einsum("btq,btf->bqf", w, vc, preferred_element_type=f32)
+    o = jnp.moveaxis(jnp.diagonal(of.reshape(B, kvh, G, kvh, hd),
+                                  axis1=1, axis2=3), -1, 1)    # (B,KV,G,hd)
+    o = o + (w_new.reshape(B, kvh, G, 1).astype(f32)
+             * vn[:, :, None, :].astype(f32))
+    o = o.astype(x.dtype).reshape(B, 1, -1)
+    return jnp.einsum("bsh,hd->bsd", o, p["wo"]), rows
 
 
 def cross_decode_attend(p: Dict, cfg: ModelConfig, x: jax.Array,

@@ -431,41 +431,35 @@ def decode_step(cfg: ModelConfig, params: Dict, tokens: jax.Array,
         lp_xs["cross"] = _regroup(dec["cross"], n_super, k)
         lp_xs["norm3"] = _regroup(dec["norm3"], n_super, k)
 
+    # the ring (and cross-attention K/V) is read-only inside the layer scan:
+    # each layer emits only its B new rows, written after the scan
+    ring_keys = [kk for kk in ("k", "v", "k_scale", "v_scale") if kk in cache]
     lc_xs = {kk: vv.reshape((n_super, k) + vv.shape[1:])
              for kk, vv in cache.items() if kk != "pos"}
 
     def body(carry, xs_i):
         x, aux = carry
         lp, lc = xs_i
-        # cross-attention K/V is read-only at decode time: not re-emitted
-        new_lc = {kk: [] for kk in lc if not kk.startswith("cross_")}
+        ys = {kk: [] for kk in lc if not kk.startswith("cross_")}
         for j in range(k):
             a_in = rms_norm(x, _idx(lp["norm1"], j), cfg.norm_eps)
-            if cfg.kv_quant:
-                a_out, k2, v2, ks2, vs2 = attn_mod.decode_attend(
-                    _idx(lp["attn"], j), cfg, a_in, pos,
-                    lc["k"][j], lc["v"][j],
-                    lc["k_scale"][j], lc["v_scale"][j])
-                new_lc["k_scale"].append(ks2)
-                new_lc["v_scale"].append(vs2)
-            else:
-                a_out, k2, v2 = attn_mod.decode_attend(
-                    _idx(lp["attn"], j), cfg, a_in, pos,
-                    lc["k"][j], lc["v"][j])
-            new_lc["k"].append(k2)
-            new_lc["v"].append(v2)
+            a_out, rows = attn_mod.decode_attend(
+                _idx(lp["attn"], j), cfg, a_in, pos,
+                {kk: lc[kk][j] for kk in ring_keys})
+            for kk, row in rows.items():
+                ys[kk].append(row)
             if cfg.family == "hybrid":
                 cw = cfg.ssm.conv_width
                 if cw > 1:
                     m_out, s2, cc2 = mamba_mod.mamba_step(
                         _idx(lp["ssm"], j), cfg, a_in,
                         lc["ssm_state"][j], lc["conv_state"][j])
-                    new_lc["conv_state"].append(cc2)
+                    ys["conv_state"].append(cc2)
                 else:
                     m_out, s2, _ = mamba_mod.mamba_step(
                         _idx(lp["ssm"], j), cfg, a_in, lc["ssm_state"][j],
                         None)
-                new_lc["ssm_state"].append(s2)
+                ys["ssm_state"].append(s2)
                 a_out = a_out + m_out
             x = x + a_out
             if cfg.is_encdec:
@@ -476,16 +470,21 @@ def decode_step(cfg: ModelConfig, params: Dict, tokens: jax.Array,
             f_in = rms_norm(x, _idx(lp["norm2"], j), cfg.norm_eps)
             f_out, aux = _ffn(cfg, lp, j, k, f_in, aux)
             x = x + f_out
-        new_lc = {kk: jnp.stack(vv) for kk, vv in new_lc.items()}
-        return (x, aux), new_lc
+        return (x, aux), {kk: jnp.stack(vv) for kk, vv in ys.items()}
 
     (x, _), new_c = maybe_scan(body, (x, jnp.zeros((), jnp.float32)),
                                (lp_xs, lc_xs), unroll=cfg.unroll)
-    new_cache = {kk: vv.reshape((L,) + vv.shape[2:])
-                 for kk, vv in new_c.items()}
-    for kk in ("cross_k", "cross_v"):
-        if kk in cache:
-            new_cache[kk] = cache[kk]
+    new_c = {kk: vv.reshape((L,) + vv.shape[2:]) for kk, vv in new_c.items()}
+    # every layer's new rows go to slot pos % C of their slot's ring: a
+    # scatter of L*B rows, in place where the caller donates the cache
+    # (one index per layer and slot keeps the cache's layout for the write)
+    lidx = jnp.arange(L)[:, None]
+    bidx = jnp.arange(pos.shape[0])[None, :]
+    slot = (pos % cache["k"].shape[2])[None, :]
+    new_cache = dict(cache)
+    for kk, vv in new_c.items():
+        new_cache[kk] = (cache[kk].at[lidx, bidx, slot].set(vv)
+                         if kk in ring_keys else vv)
     new_cache["pos"] = pos + 1
     h = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return _unembed(cfg, params, h), new_cache

@@ -6,6 +6,8 @@ decode per engine step); requests are admitted into free slots by running a
 single-row prefill (prompt bucketed to a power of two to bound recompiles —
 right-padding is masked by construction, see ``prefill_step``) and
 scattering the row into the batch cache. Completed rows free their slot.
+The decode step donates the batch cache: each step writes its new K/V rows
+into the ring in place, and the device holds one cache.
 
 This is the vLLM-style core scaled down: the KV "pages" are per-slot ring
 buffers; at production scale the same engine runs under pjit with the cache
@@ -85,7 +87,11 @@ class ServingEngine:
         self.finished: List[Request] = []
         self._rid = 0
         self._rng = jax.random.PRNGKey(0)
+        # the decode program, (params, tokens, cache) -> (logits, cache');
+        # ``step`` runs whatever it holds (a test may wrap it) under a jit
+        # that donates the cache
         self._decode = _named_jit(decode_step, cfg)
+        self._donating = (None, None)
         self._prefill = {}
         # this engine's totals of the ``serving.*`` counters it adds to
         # ``profiling.COUNTERS`` once per step
@@ -97,6 +103,15 @@ class ServingEngine:
     def _empty_cache(self):
         specs = cache_specs(self.cfg, self.max_batch, self.max_len)
         return {k: jnp.zeros(v.shape, v.dtype) for k, v in specs.items()}
+
+    def _decode_donated(self):
+        """``_decode`` under a jit that donates the cache: the new cache
+        takes the old one's buffers, so a step writes only its new K/V rows
+        into the ring and the device holds one cache."""
+        if self._donating[0] is not self._decode:
+            self._donating = (self._decode,
+                              jax.jit(self._decode, donate_argnums=2))
+        return self._donating[1]
 
     def _prefill_fn(self, bucket: int):
         if bucket not in self._prefill:
@@ -186,8 +201,8 @@ class ServingEngine:
         for i in active:
             tokens[i, 0] = self.slots[i].out_ids[-1]
         with profiling.span("serving.decode"):
-            logits, self.cache = self._decode(self.params,
-                                              jnp.asarray(tokens), self.cache)
+            logits, self.cache = self._decode_donated()(
+                self.params, jnp.asarray(tokens), self.cache)
         with profiling.span("serving.sample"):
             self._rng, k = jax.random.split(self._rng)
             nxt = np.asarray(sample(logits[:, -1].astype(jnp.float32), k))

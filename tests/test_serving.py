@@ -6,8 +6,22 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.models import Init, init_model, unbox
+from repro.models import Init, init_model, prefill_step, unbox
+from repro.models.model import _unembed, forward
 from repro.serving import ByteTokenizer, ServingEngine, sample
+from repro.serving.engine import Request
+
+# attention families that decode against the K/V ring, as reduced configs
+# (with the byte tokenizer's vocabulary): (arch, replaced fields)
+FAMILIES = {
+    "dense": ("granite-3-2b", {}),
+    "kv_quant": ("granite-3-2b", {"kv_quant": True}),
+    "moe": ("llama4-maverick-400b-a17b", {}),     # chunked-local attention
+    "hybrid": ("hymba-1.5b", {}),
+    "encdec": ("seamless-m4t-large-v2", {}),
+    "sliding_window": ("mixtral-8x22b", {}),
+}
+RING_KEYS = ("k", "v", "k_scale", "v_scale")
 
 
 def engine(max_batch=3, max_len=96, family_arch="dcache-agent-150m"):
@@ -97,3 +111,82 @@ def test_max_len_cap_terminates():
     eng.run_until_done()
     assert r.done
     assert len(r.out_ids) < 30
+
+
+def _family(family, **kw):
+    arch, fields = FAMILIES[family]
+    cfg = dataclasses.replace(get_config(arch).reduced(), vocab_size=512,
+                              dtype="float32", **fields)
+    cfg = dataclasses.replace(cfg, **kw)
+    params, _ = unbox(init_model(Init(jax.random.PRNGKey(0),
+                                      dtype=cfg.jnp_dtype), cfg))
+    return cfg, params
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_decode_step_donates_the_cache(family):
+    """A step consumes the cache it was given, and the compiled decode
+    writes in place: its output aliases the whole input cache, and it needs
+    less scratch than the K/V ring (a step that wrote every layer's ring
+    back needs more). float32, because the CPU backend computes bfloat16
+    in float32 and would hold float32 copies of the ring."""
+    # 8 layers and rings of 1024-2048 slots, so that the ring outweighs the
+    # step's other scratch
+    wide = {"moe": {"attn_chunk": 1024}, "hybrid": {"sliding_window": 1024},
+            "sliding_window": {"sliding_window": 1024}}
+    cfg, params = _family(family, n_layers=8, **wide.get(family, {}))
+    eng = ServingEngine(cfg, params, max_batch=4, max_len=2048)
+    # a request seated in slot 0: the decode step is what is under test
+    eng.slots[0] = Request(rid=0, prompt_ids=[1], max_new_tokens=8,
+                           out_ids=[5])
+    before = eng.cache
+    eng.step()
+    assert all(v.is_deleted() for v in before.values())
+    assert not any(v.is_deleted() for v in eng.cache.values())
+
+    tokens = jnp.zeros((eng.max_batch, 1), jnp.int32)
+    mem = eng._decode_donated().lower(
+        eng.params, tokens, eng.cache).compile().memory_analysis()
+    cache_bytes = sum(v.nbytes for v in eng.cache.values())
+    ring_bytes = sum(eng.cache[k].nbytes for k in RING_KEYS
+                     if k in eng.cache)
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < ring_bytes, (mem.temp_size_in_bytes,
+                                                 ring_bytes)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_donated_decode_matches_forward_past_a_wrapped_ring(family):
+    """The engine's donated decode, stepped until its ring of C slots has
+    wrapped (pos >= C), gives the logits of a forward pass over the whole
+    sequence that sees the same last C tokens: the config's own window or
+    chunk of C, else a sliding window of C. With ``kv_quant`` the ring
+    holds int8 codes, so its logits differ by the quantisation."""
+    cfg, params = _family(family)
+    B, S, T, C = 2, 5, 11, 8
+    rng = np.random.default_rng(3)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, S + T)),
+                       jnp.int32)
+    extra = {}
+    if cfg.is_encdec:
+        extra["frames"] = jnp.asarray(rng.normal(size=(B, 4, cfg.d_model)),
+                                      cfg.jnp_dtype)
+    assert (cfg.sliding_window or C) == C and (cfg.attn_chunk or C) == C
+    ref_cfg = dataclasses.replace(cfg, kv_quant=False)
+    if cfg.sliding_window is None and cfg.attn_chunk is None:
+        ref_cfg = dataclasses.replace(ref_cfg, sliding_window=C)
+    h, _, _ = forward(ref_cfg, params, {"tokens": toks, **extra},
+                      is_train=False)
+    ref = np.asarray(_unembed(cfg, params, h))[:, S:]
+
+    cache, _ = prefill_step(cfg, params, {"tokens": toks[:, :S], **extra},
+                            max_len=C)
+    step = ServingEngine(cfg, params, max_batch=B,
+                         max_len=C)._decode_donated()
+    got = []
+    for t in range(S, S + T):
+        logits, cache = step(params, toks[:, t:t + 1], cache)
+        got.append(np.asarray(logits[:, 0]))
+    assert int(cache["pos"][0]) == S + T > 2 * C - 1
+    tol = 5e-2 if cfg.kv_quant else 2e-3
+    np.testing.assert_allclose(np.stack(got, 1), ref, atol=tol, rtol=tol)

@@ -1,12 +1,15 @@
 """Compile for a described TPU v5e chip, with no chip attached.
 
-Each Pallas kernel at the widths ``chip_smoke.py`` runs, and the full-width
-dcache-agent-150m serving steps, go through the TPU compiler here, so what
-the chip's compiler refuses fails this file instead of a chip run. Nothing
-executes: arguments are shapes only.
+Each Pallas kernel at the widths ``chip_smoke.py`` runs, the full-width
+dcache-agent-150m serving steps, and granite-3-2b's decode step at the
+benchmark's size, go through the TPU compiler here, so what the chip's
+compiler refuses fails this file instead of a chip run. Nothing executes:
+arguments are shapes only.
 """
+import dataclasses
 import functools
 import importlib
+import math
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +23,7 @@ from repro.models.common import Init, unbox
 from repro.models.model import decode_step, init_model, prefill_step
 
 CFG = get_config("dcache-agent-150m")
+GRANITE = get_config("granite-3-2b")
 WKV_CFG = get_config("rwkv6-7b")
 BATCH, MAX_LEN = PRESETS["full"]["max_batch"], PRESETS["full"]["max_len"]
 SEQ = 2048                       # the prefill bucket of a few-shot prompt
@@ -87,9 +91,9 @@ def test_wkv_compiles_for_bf16_inputs(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _params(sharding):
-    ini = Init(jax.random.PRNGKey(0), dtype=CFG.jnp_dtype, abstract=True)
-    params, _ = unbox(init_model(ini, CFG))
+def _params(sharding, cfg=CFG):
+    ini = Init(jax.random.PRNGKey(0), dtype=cfg.jnp_dtype, abstract=True)
+    params, _ = unbox(init_model(ini, cfg))
     return jax.tree.map(lambda a: _spec(sharding, a.shape, a.dtype), params)
 
 
@@ -116,3 +120,24 @@ def test_prefill_step_compiles_full_width(one_chip):
         _params(one_chip), {"tokens": _spec(one_chip, (1, SEQ), jnp.int32)},
         true_lens=_spec(one_chip, (1,), jnp.int32)).compile()
     _fits_one_chip(compiled)
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["bf16", "int8"])
+def test_donated_decode_writes_the_ring_in_place(one_chip, kv_quant):
+    """granite-3-2b's decode step at the benchmark's 8 slots x 4096, with
+    the cache donated as the serving engine donates it: the output aliases
+    the whole cache, and the step needs less scratch than the K ring alone,
+    so no op writes the ring back whole or copies it to another layout."""
+    cfg = dataclasses.replace(GRANITE, kv_quant=kv_quant)
+    batch, max_len = 8, 4096
+    specs = cache_specs(cfg, batch, max_len)
+    cache = {k: _spec(one_chip, v.shape, v.dtype) for k, v in specs.items()}
+    step = jax.jit(functools.partial(decode_step, cfg), donate_argnums=2)
+    compiled = step.lower(_params(one_chip, cfg),
+                          _spec(one_chip, (batch, 1), jnp.int32),
+                          cache).compile()
+    nbytes = {k: math.prod(v.shape) * v.dtype.itemsize
+              for k, v in specs.items()}
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(nbytes.values())
+    assert m.temp_size_in_bytes < nbytes["k"], m.temp_size_in_bytes
