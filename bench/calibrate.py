@@ -60,7 +60,7 @@ def sweep(args):
     rate are taken over the requests of all its seeds together."""
     from repro.launch.serve import build_engine
     cell, device, _ = _setup(args.workload)
-    cfg = harness.program_config(cell.config)
+    cfg = harness.program_config(cell.config, cell.reference)
     sizes = cell.config["engine"]
     eng = build_engine(cfg, max_batch=sizes["max_batch"],
                        max_len=sizes["max_len"],

@@ -1,12 +1,15 @@
 """One run of one benchmark cell on the chip.
 
 The cell, its configuration and its traffic mix are found by name through
-``BENCHMARK.json``; each metric is read by ``bench/metrics/<name>.py``. A
-run builds the program's ``ServingEngine`` (weights drawn on the device
-from the seed), warms up the shapes the cell uses, drives the window
-through ``ServingEngine.submit`` and ``ServingEngine.step``, follows every
-request due in the window to its end, and then holds a sample of the served
-tokens to the plain float32 reference in ``bench/reference.py``.
+``BENCHMARK.json``; each metric is read by ``bench/metrics/<name>.py``. The
+configuration file names the two modules that know its model: its plain
+float32 reference (``"reference"``) and the work counts of its steps
+(``"work"``). A run builds the program's ``ServingEngine`` (weights drawn on
+the device from the seed), warms up the shapes the cell uses, drives the
+mix's lead-in and then the window through ``ServingEngine.submit`` and
+``ServingEngine.step``, follows every request due in the window to its end
+while the schedule goes on, and then holds a sample of the served tokens to
+the configuration's reference.
 """
 from __future__ import annotations
 
@@ -22,11 +25,13 @@ import shutil
 import sys
 import tempfile
 import time
+import types
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from bench import devtrace, reference, traffic
+from bench import devtrace, traffic
+from bench.reference_common import gaps, served_rows
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -51,6 +56,8 @@ class Cell:
     name: str
     chips: int
     config: Dict            # the configuration file's contents
+    reference: types.ModuleType     # the modules the configuration names
+    work: types.ModuleType
     mix: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
@@ -62,6 +69,10 @@ def _for_cell(metrics: List[Dict], cell: str) -> List[Dict]:
 
 
 def find_cell(name: str, bench_json: pathlib.Path = BENCHMARK_JSON) -> Cell:
+    """The cell ``name``. Its configuration file, the modules that file
+    names and its limits are found beside ``bench_json``; its mix and the
+    metric readers in this directory."""
+    root = bench_json.parent
     bench = json.loads(bench_json.read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -69,24 +80,34 @@ def find_cell(name: str, bench_json: pathlib.Path = BENCHMARK_JSON) -> Cell:
                        f"known: {sorted(cells)}")
     w = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
-    return Cell(name=name, chips=w["chips"],
-                config=json.loads((ROOT / conf["file"]).read_text()),
+    config = json.loads((root / conf["file"]).read_text())
+    named = {}
+    for key in ("reference", "work"):
+        if key not in config:
+            raise ValueError(f"{conf['file']} names no {key!r} module")
+        named[key] = load_module(root / config[key],
+                                 f"bench_{key}_{w['config']}")
+    return Cell(name=name, chips=w["chips"], config=config, **named,
                 mix=traffic.load_mix(w["traffic"]),
                 end_to_end=_for_cell(bench["end_to_end"], name),
                 per_layer=_for_cell(bench["per_layer"], name),
-                limits=json.loads(
-                    (BENCH / "limits" / f"{name}.json").read_text()))
+                limits=json.loads((root / "bench" / "limits"
+                                   / f"{name}.json").read_text()))
+
+
+def load_module(path: pathlib.Path, name: str) -> types.ModuleType:
+    """The Python file at ``path``, loaded as module ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    if spec is None or not path.is_file():
+        raise FileNotFoundError(f"no module {name!r} at {path}")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_reader(name: str) -> Callable:
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    if spec is None or not path.is_file():
-        raise FileNotFoundError(f"no reader for metric {name!r} ({path})")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(BENCH / "metrics" / f"{name}.py",
+                       f"bench_metric_{name}").read
 
 
 def peaks_for(kind: str) -> Dict:
@@ -124,31 +145,12 @@ class CompileClock:
             self.count += 1
 
 
-def model_dict(cfg) -> Dict:
-    """The ``model`` block a configuration file states, read off a
-    ``ModelConfig``; raises where the program's model is not the plain dense
-    decoder that ``bench/reference.py`` computes."""
-    plain = (cfg.family == "dense" and cfg.act == "swiglu" and not cfg.moe
-             and not cfg.qkv_bias and not cfg.qk_norm and not cfg.kv_quant
-             and cfg.sliding_window is None and cfg.attn_chunk is None
-             and cfg.frontend == "none")
-    if not plain:
-        raise ValueError(f"{cfg.name}: not a plain dense decoder")
-    return {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
-            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-            "head_dim": cfg.head_dim_, "d_ff": cfg.d_ff,
-            "vocab_size": cfg.vocab_size,
-            "tie_embeddings": cfg.tie_embeddings,
-            "rope_theta": float(cfg.rope_theta),
-            "norm_eps": float(cfg.norm_eps), "dtype": cfg.dtype}
-
-
-def program_config(config: Dict):
+def program_config(config: Dict, reference: types.ModuleType):
     """The program's ``ModelConfig`` for a configuration file; it has to be
-    the model the file states."""
+    the model the file states, as the file's ``reference`` reads it."""
     from repro.configs import get_config
     cfg = get_config(config["arch"])
-    got = model_dict(cfg)
+    got = reference.model_block(cfg)
     if got != config["model"]:
         diff = {k: (got.get(k), v) for k, v in config["model"].items()
                 if got.get(k) != v}
@@ -191,6 +193,7 @@ class Step:
 class Run:
     cell: str
     model: Dict
+    work: types.ModuleType  # the configuration's work counts
     seed: int
     seconds: float
     setup_s: float
@@ -280,6 +283,9 @@ class _Tracer:
         self.overhead_s = 0.0
         self.paused: List[Tuple[float, float]] = []
 
+    def open(self):
+        """The window opens; the trace starts later."""
+
     def start(self):
         """Starts the trace inside the window; the seconds it takes (up to
         a few on the chip) are a pause of the loop."""
@@ -322,25 +328,31 @@ def warm_up(eng, rows: List[Dict]) -> None:
 def drive(eng, tr: traffic.Traffic, seconds: float,
           tracer: Optional[_Tracer] = None,
           trace_seconds: float = TRACE_SECONDS):
-    """The measured window and the drain after it."""
+    """The lead-in, the measured window and the drain after it. Requests
+    due before the open or after the close load the engine and are not
+    returned; once every request due in the window has ended (or the drain
+    has run out), those still in the engine are dropped from it."""
     pc = time.perf_counter
     reqs: List[Req] = []
     live: List[Req] = []
     steps: List[Step] = []
-    pending = collections.deque(tr.specs)
+    pending = collections.deque(tr.lead_in + tr.specs)
+    after = tr.after()
     backlog = None if tr.open_loop else tr.backlog()
-    t_open = pc()
+    t_open = pc() + tr.lead_in_s
     t_close = t_open + seconds
-    trace_at = t_close - trace_seconds if tracer else math.inf
+    trace_at = max(t_open, t_close - trace_seconds) if tracer else math.inf
     tracing = False
+    opened = tracer is None
 
     def submit(spec, due):
         h = eng.submit(spec.prompt, max_new_tokens=spec.max_new_tokens)
         r = Req(spec, due, pc(), h)
-        reqs.append(r)
+        if spec.due_s is None or 0 <= spec.due_s < seconds:
+            reqs.append(r)
         live.append(r)
 
-    def step(in_window):
+    def step():
         t0 = pc()
         with span("engine.step"):
             eng.step()
@@ -358,12 +370,16 @@ def drive(eng, tr: traffic.Traffic, seconds: float,
             if grew and n >= 2:
                 keys.append(len(h.prompt_ids) + n - 1)
         live[:] = [r for r in live if not r.done]
-        steps.append(Step(t0, t1, admitted, keys, tracing, in_window))
+        steps.append(Step(t0, t1, admitted, keys, tracing,
+                          t_open <= t0 < t_close))
 
     while True:
         now = pc()
         if now >= t_close:
             break
+        if not opened and now >= t_open:
+            tracer.open()
+            opened = True
         if not tracing and now >= trace_at:
             tracer.start()
             tracing = True
@@ -376,7 +392,7 @@ def drive(eng, tr: traffic.Traffic, seconds: float,
                 while len(eng.waiting) < eng.max_batch:
                     submit(next(backlog), pc())
         if live:
-            step(True)
+            step()
         else:
             nxt = t_open + pending[0].due_s if pending else t_close
             with span("bench.wait_arrival"):
@@ -395,9 +411,15 @@ def drive(eng, tr: traffic.Traffic, seconds: float,
         withdrawn = sum(1 for r in reqs if id(r.handle) in queued)
         reqs = [r for r in reqs if id(r.handle) not in queued]
         live[:] = [r for r in live if id(r.handle) not in queued]
+    nxt = next(after, None)
     deadline = pc() + DRAIN_SECONDS
-    while live and pc() < deadline:
-        step(False)
+    while any(not r.done for r in reqs) and pc() < deadline:
+        while nxt is not None and t_open + nxt.due_s <= pc():
+            submit(nxt, t_open + nxt.due_s)     # the schedule goes on
+            nxt = next(after, None)
+        step()
+    eng.waiting.clear()                 # none of these is measured
+    eng.slots[:] = [None] * len(eng.slots)
     return reqs, withdrawn, steps, t_open, t_close
 
 
@@ -425,23 +447,24 @@ def sample_rows(sample: List[Req]):
     seqs = [(list(r.handle.prompt_ids), list(r.handle.out_ids))
             for r in sample]
     length = -(-max(len(p) + len(s) for p, s in seqs) // 256) * 256
-    return reference.served_rows(seqs, length)
+    return served_rows(seqs, length)
 
 
-def logit_gaps(model: Dict, seed: int, rows, quant: Optional[str] = None):
-    """Per served token, its gap below the reference's best logit; with
+def logit_gaps(reference: types.ModuleType, model: Dict, seed: int, rows,
+               quant: Optional[str] = None):
+    """Per served token, its gap below ``reference``'s best logit; with
     ``quant`` also the gaps of the tokens the quantised control puts first."""
     tokens, rb, rp, tok = rows
     w = reference.make_weights(model, engine_seed(seed))
     ref = np.asarray(reference.logits_at(model, w, tokens, rb, rp))
     served = np.where(tok < model["vocab_size"], tok, 0)
-    g = reference.gaps(ref, served)
+    g = gaps(ref, served)
     g[tok >= model["vocab_size"]] = np.inf
     ctl = None
     if quant:
         low = np.asarray(reference.logits_at(model, w, tokens, rb, rp,
                                              quant=quant))
-        ctl = reference.gaps(ref, low.argmax(axis=1))
+        ctl = gaps(ref, low.argmax(axis=1))
     del w
     return g, ctl
 
@@ -459,7 +482,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     from repro.launch.serve import build_engine
 
     if cfg is None:
-        cfg = program_config(cell.config)
+        cfg = program_config(cell.config, cell.reference)
         model = cell.config["model"]
     sizes = cell.config["engine"]
     eng = build_engine(cfg, max_batch=sizes["max_batch"],
@@ -475,10 +498,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     compiles, compile_s = clock.count, clock.seconds
     jax.block_until_ready(eng.cache)
     stats = jax.devices()[0].memory_stats() or {}
-    run = Run(cell=cell.name, model=model, seed=seed, seconds=seconds,
-              setup_s=setup_s, t_open=t_open, t_close=t_close,
-              requests=reqs, withdrawn=withdrawn, steps=steps,
-              compiles_in_window=compiles, compile_s_in_window=compile_s,
+    run = Run(cell=cell.name, model=model, work=cell.work, seed=seed,
+              seconds=seconds, setup_s=setup_s, t_open=t_open,
+              t_close=t_close, requests=reqs, withdrawn=withdrawn,
+              steps=steps, compiles_in_window=compiles,
+              compile_s_in_window=compile_s,
               memory_peak_bytes=int(stats.get("peak_bytes_in_use", 0)),
               peaks=peaks or {})
     if tracer:
@@ -491,7 +515,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     unfinished = sum(1 for r in reqs if not r.done)
     sample = pick_sample(reqs, seed)
     if sample:
-        g, ctl = logit_gaps(model, seed, sample_rows(sample), control)
+        g, ctl = logit_gaps(cell.reference, model, seed,
+                            sample_rows(sample), control)
         gap = float(min(g.max(), NO_ANSWER))
         ctl_gap = None if ctl is None else float(ctl.max())
         n_tok = int(len(g))

@@ -1,7 +1,6 @@
 """Model decode, whole step: useful operations of the decode steps in the
 traced window (active slots, live keys, real vocabulary) over the device
 time of ``decode_step`` times the chip's peak."""
-from bench import work
 from bench.stats import share_pct
 
 
@@ -12,5 +11,5 @@ def read(run):
     dev_s = run.device_seconds("decode_step")
     if not steps or dev_s is None:
         return None
-    flops = sum(work.decode_flops(run.model, s.keys) for s in steps)
+    flops = sum(run.work.decode_flops(run.model, s.keys) for s in steps)
     return share_pct(flops, dev_s * run.peaks["bf16_flops_per_s"])

@@ -2,14 +2,13 @@
 steps, each the larger of its operations over peak FLOP/s and its bytes
 (weights once, live K/V) over HBM bandwidth, over the device time of
 ``decode_step``. ``bound`` says which of the two bounds it."""
-from bench import work
 from bench.stats import share_pct
 
 
 def bound(run, step):
-    t_flops = work.decode_flops(run.model, step.keys) \
+    t_flops = run.work.decode_flops(run.model, step.keys) \
         / run.peaks["bf16_flops_per_s"]
-    t_bytes = work.decode_bytes(run.model, step.keys) \
+    t_bytes = run.work.decode_bytes(run.model, step.keys) \
         / run.peaks["hbm_bytes_per_s"]
     return max(t_flops, t_bytes), ("compute" if t_flops > t_bytes
                                    else "memory")

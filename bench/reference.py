@@ -1,10 +1,9 @@
 """Plain float32 reference of the served dense decoder, independent of the
 program: it imports nothing from ``repro`` and takes nothing the program
 made. It draws the weights again from the run's seed, in the order and by
-the rule the program's initialiser documents (``Init.param``: a truncated
-normal on [-2, 2] times ``scale / sqrt(fan_in)``, cast to the served
-dtype), and runs the forward pass layer by layer in float32 at the highest
-matmul precision.
+the rule the program's initialiser documents
+(``reference_common.draw_weights``), and runs the forward pass layer by
+layer in float32 at the highest matmul precision.
 
 The architecture is the one a configuration file's ``model`` block states:
 pre-norm decoder layers (RMSNorm, grouped-query attention with half-split
@@ -15,18 +14,60 @@ unembedding over a vocabulary padded to a multiple of 256.
 and the unembedding takes its inputs rounded to float8 e4m3 (per-row scales
 for activations, per-column for weights), the step that would tempt a
 faster build. Attention itself stays in float32.
+
+A configuration file names this module under ``"reference"``; the harness
+calls ``model_block``, ``make_weights`` and ``logits_at``
+(``bench/reference_common.py`` says what each gives).
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-F8_MAX = 448.0          # largest finite float8_e4m3fn
+from bench.reference_common import draw_weights, matmul, rms
+
+
+def model_block(cfg) -> Dict:
+    """The ``model`` block a configuration file states, read off the
+    attributes of the program's ``ModelConfig``; raises where that is not
+    the plain dense decoder this reference computes."""
+    plain = (cfg.family == "dense" and cfg.act == "swiglu" and not cfg.moe
+             and not cfg.qkv_bias and not cfg.qk_norm and not cfg.kv_quant
+             and cfg.sliding_window is None and cfg.attn_chunk is None
+             and cfg.frontend == "none")
+    if not plain:
+        raise ValueError(f"{cfg.name}: not a plain dense decoder")
+    return {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
+            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
+            "head_dim": cfg.head_dim_, "d_ff": cfg.d_ff,
+            "vocab_size": cfg.vocab_size,
+            "tie_embeddings": cfg.tie_embeddings,
+            "rope_theta": float(cfg.rope_theta),
+            "norm_eps": float(cfg.norm_eps), "dtype": cfg.dtype}
+
+
+def published_block(pub: Dict) -> Dict[str, Tuple[str, object]]:
+    """Per key of the ``model`` block, the key of the published
+    ``config.json`` it is read from and the value there."""
+    hd = pub.get("head_dim") or pub["hidden_size"] // pub[
+        "num_attention_heads"]
+    return {"n_layers": ("num_hidden_layers", pub["num_hidden_layers"]),
+            "d_model": ("hidden_size", pub["hidden_size"]),
+            "n_heads": ("num_attention_heads", pub["num_attention_heads"]),
+            "n_kv_heads": ("num_key_value_heads",
+                           pub["num_key_value_heads"]),
+            "head_dim": ("head_dim", hd),
+            "d_ff": ("intermediate_size", pub["intermediate_size"]),
+            "vocab_size": ("vocab_size", pub["vocab_size"]),
+            "tie_embeddings": ("tie_word_embeddings",
+                               pub["tie_word_embeddings"]),
+            "rope_theta": ("rope_theta", float(pub["rope_theta"])),
+            "norm_eps": ("rms_norm_eps", float(pub["rms_norm_eps"])),
+            "dtype": ("torch_dtype", pub["torch_dtype"])}
 
 
 def padded_vocab(m: Dict) -> int:
@@ -53,30 +94,9 @@ def param_plan(m: Dict) -> List[Tuple[str, Tuple[int, ...], str, float]]:
     return plan
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _draw(key, shape, std, dtype):
-    x = jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32)
-    return (x * np.float64(std)).astype(dtype)
-
-
 def make_weights(m: Dict, seed: int) -> Dict[str, jax.Array]:
     """The served weights, drawn again from ``seed`` in the served dtype."""
-    dtype = jnp.dtype(m["dtype"])
-    key = jax.random.PRNGKey(seed)
-    w = {}
-    for name, shape, init, scale in param_plan(m):
-        if init == "ones":
-            w[name] = jnp.ones(shape, dtype)
-            continue
-        key, k = jax.random.split(key)
-        fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
-        w[name] = _draw(k, shape, float(scale / np.sqrt(max(fan_in, 1))),
-                        dtype)
-    return w
-
-
-def _rms(x, g, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
+    return draw_weights(param_plan(m), m["dtype"], seed)
 
 
 def _rope(x, pos, theta):
@@ -90,28 +110,16 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _fp8(x, axis):
-    s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / F8_MAX
-    s = jnp.where(s == 0, 1.0, s)
-    return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-
-
-def _matmul(a, b, quant):
-    if quant == "fp8":
-        a, b = _fp8(a, -1), _fp8(b, 0)
-    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
-
-
 def _layer(m: Dict, quant: Optional[str], q_chunk: int, x, lw):
     f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
     B, S, D = x.shape
     hd, hq, kv = m["head_dim"], m["n_heads"], m["n_kv_heads"]
     g = hq // kv
     pos = jnp.arange(S)
-    a = _rms(x, f32(lw["norm1"]), m["norm_eps"])
-    q = _matmul(a, f32(lw["wq"]), quant).reshape(B, S, hq, hd)
-    k = _matmul(a, f32(lw["wk"]), quant).reshape(B, S, kv, hd)
-    v = _matmul(a, f32(lw["wv"]), quant).reshape(B, S, kv, hd)
+    a = rms(x, f32(lw["norm1"]), m["norm_eps"])
+    q = matmul(a, f32(lw["wq"]), quant).reshape(B, S, hq, hd)
+    k = matmul(a, f32(lw["wk"]), quant).reshape(B, S, kv, hd)
+    v = matmul(a, f32(lw["wv"]), quant).reshape(B, S, kv, hd)
     q = _rope(q, pos, m["rope_theta"]).reshape(B, S, kv, g, hd)
     k = _rope(k, pos, m["rope_theta"])
     outs = []
@@ -125,11 +133,11 @@ def _layer(m: Dict, quant: Optional[str], q_chunk: int, x, lw):
         outs.append(jnp.einsum("bkgct,btkh->bckgh", p, v,
                                precision=jax.lax.Precision.HIGHEST))
     o = jnp.concatenate(outs, axis=1).reshape(B, S, hq * hd)
-    x = x + _matmul(o, f32(lw["wo"]), quant)
-    f = _rms(x, f32(lw["norm2"]), m["norm_eps"])
-    h = jax.nn.silu(_matmul(f, f32(lw["w_gate"]), quant)) \
-        * _matmul(f, f32(lw["w_up"]), quant)
-    return x + _matmul(h, f32(lw["w_down"]), quant)
+    x = x + matmul(o, f32(lw["wo"]), quant)
+    f = rms(x, f32(lw["norm2"]), m["norm_eps"])
+    h = jax.nn.silu(matmul(f, f32(lw["w_gate"]), quant)) \
+        * matmul(f, f32(lw["w_up"]), quant)
+    return x + matmul(h, f32(lw["w_down"]), quant)
 
 
 LAYER_KEYS = ("norm1", "norm2", "wq", "wk", "wv", "wo", "w_up", "w_down",
@@ -146,27 +154,10 @@ def _forward_rows(m_items, quant, q_chunk, w, tokens, rows_b, rows_p):
         return _layer(m, quant, q_chunk, x, lw), None
 
     x, _ = jax.lax.scan(body, x, {k: w[k] for k in LAYER_KEYS})
-    h = _rms(x[rows_b, rows_p], w["final_norm"].astype(jnp.float32),
-             m["norm_eps"])
+    h = rms(x[rows_b, rows_p], w["final_norm"].astype(jnp.float32),
+            m["norm_eps"])
     wu = w["embed"].T if m["tie_embeddings"] else w["unembed"]
-    return _matmul(h, wu.astype(jnp.float32), quant)[:, :m["vocab_size"]]
-
-
-def served_rows(seqs: Sequence[Tuple[List[int], List[int]]], length: int):
-    """Pad ``prompt + served`` sequences to ``length``; return the tokens
-    and, for every served token, (row, position that predicts it, token)."""
-    tokens = np.zeros((len(seqs), length), np.int32)
-    rb, rp, tok = [], [], []
-    for b, (prompt, served) in enumerate(seqs):
-        full = list(prompt) + list(served)
-        if len(full) > length:
-            raise ValueError(f"sequence of {len(full)} over {length}")
-        tokens[b, :len(full)] = full
-        for j, t in enumerate(served):
-            rb.append(b)
-            rp.append(len(prompt) - 1 + j)
-            tok.append(t)
-    return tokens, np.asarray(rb), np.asarray(rp), np.asarray(tok)
+    return matmul(h, wu.astype(jnp.float32), quant)[:, :m["vocab_size"]]
 
 
 def logits_at(m: Dict, w, tokens, rows_b, rows_p, quant: Optional[str] = None,
@@ -177,9 +168,3 @@ def logits_at(m: Dict, w, tokens, rows_b, rows_p, quant: Optional[str] = None,
                              jnp.asarray(tokens), jnp.asarray(rows_b),
                              jnp.asarray(rows_p))
 
-
-def gaps(ref_logits, chosen) -> np.ndarray:
-    """How far each chosen token's reference logit lies below the best."""
-    ref = np.asarray(ref_logits, np.float64)
-    chosen = np.asarray(chosen)
-    return ref.max(axis=1) - ref[np.arange(len(chosen)), chosen]

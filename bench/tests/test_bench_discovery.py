@@ -43,28 +43,50 @@ def test_every_metric_has_a_reader():
 CONFIG_FILES = sorted((harness.BENCH / "configs").glob("*.json"))
 
 
+def _named(data, key):
+    """The module the configuration names under ``key``, which lies in the
+    benchmark's own directories."""
+    assert data[key].split("/")[0] in BENCH["paths"]
+    return harness.load_module(harness.ROOT / data[key],
+                               f"test_{key}_{data['name']}")
+
+
+def _departures(data, ref):
+    """The published keys whose value the ``model`` block departs from,
+    by the mapping of the configuration's own reference."""
+    return {pub for key, (pub, want)
+            in ref.published_block(data["published"]).items()
+            if data["model"][key] != want}
+
+
 @pytest.mark.parametrize("path", CONFIG_FILES, ids=lambda p: p.stem)
 def test_config_file_is_the_programs_model(path):
-    """The file states what the program serves: a later change to the
-    program's config fails here, not on the chip."""
+    """The file states what the program serves, as its own reference reads
+    it, and lists every key it cuts from the published config: a later
+    change to the program's config fails here, not on the chip."""
     from repro.configs import get_config
     data = json.loads(path.read_text())
-    assert data["name"] == path.stem and data["reduced"] == []
+    assert data["name"] == path.stem
     for conf in BENCH["configs"]:
         if conf["name"] == data["name"]:
             assert conf["file"] == f"bench/configs/{path.name}"
             assert data["source"] == conf["source"]
             assert conf["reduced"] == data["reduced"]
-    assert harness.program_config(data) is get_config(data["arch"])
-    pub = data["published"]
-    m = data["model"]
-    assert (pub["num_hidden_layers"], pub["hidden_size"],
-            pub["num_attention_heads"], pub["num_key_value_heads"],
-            pub["intermediate_size"], pub["vocab_size"],
-            pub["tie_word_embeddings"]) == (
-        m["n_layers"], m["d_model"], m["n_heads"], m["n_kv_heads"],
-        m["d_ff"], m["vocab_size"], m["tie_embeddings"])
-    assert m["head_dim"] * m["n_heads"] == pub["hidden_size"]
+    ref = _named(data, "reference")
+    assert callable(_named(data, "work").decode_bytes)
+    assert harness.program_config(data, ref) is get_config(data["arch"])
+    assert _departures(data, ref) <= set(data["reduced"])
+
+
+def test_a_cut_configuration_must_list_its_cuts():
+    data = json.loads((harness.BENCH / "configs"
+                       / "granite-3-2b.json").read_text())
+    ref = _named(data, "reference")
+    assert _departures(data, ref) == set()
+    cut = dict(data, model=dict(data["model"], n_layers=10))
+    assert _departures(cut, ref) == {"num_hidden_layers"}
+    with pytest.raises(ValueError, match="n_layers"):
+        harness.program_config(cut, ref)
 
 
 @pytest.mark.parametrize("mix", sorted({w["traffic"]

@@ -2,11 +2,13 @@
 a chip, and the control."""
 import dataclasses
 import json
+import time
+import types
 
 import numpy as np
 import pytest
 
-from bench import harness
+from bench import harness, traffic
 from bench.tests.helpers import cell_for, run_tiny
 
 DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}
@@ -82,10 +84,10 @@ def test_queue_wait_leaves_out_the_benchmarks_own_pause():
     reqs = [harness.Req(None, 10.0 + i, 10.0 + i, H(), admit_t=10.5 + i)
             for i in range(9)]
     reqs.append(harness.Req(None, 20.0, 20.0, H(), admit_t=33.0))
-    run = harness.Run(cell="c", model={}, seed=1, seconds=10.0, setup_s=1.0,
-                      t_open=10.0, t_close=20.5, requests=reqs, withdrawn=0,
-                      steps=[], compiles_in_window=0, compile_s_in_window=0.0,
-                      memory_peak_bytes=1, peaks={},
+    run = harness.Run(cell="c", model={}, work=None, seed=1, seconds=10.0,
+                      setup_s=1.0, t_open=10.0, t_close=20.5, requests=reqs,
+                      withdrawn=0, steps=[], compiles_in_window=0,
+                      compile_s_in_window=0.0, memory_peak_bytes=1, peaks={},
                       paused=[(20.5, 32.5)])
     assert run.unpaused(20.0, 33.0) == pytest.approx(1.0)
     assert run.unpaused(10.0, 10.5) == pytest.approx(0.5)
@@ -132,3 +134,54 @@ def test_pick_sample_holds_the_longest():
     assert len(picked) <= harness.SAMPLE_MAX_REQUESTS
     assert picked == harness.pick_sample(reqs, 5)
     assert np.all([r.done for r in picked])
+
+
+class _SlowEngine:
+    """One slot; a request holds it for ``steps`` steps of ``dt`` seconds."""
+    max_batch = 1
+
+    def __init__(self, steps=40, dt=0.004):
+        self.steps, self.dt = steps, dt
+        self.waiting, self.slots, self.sent = [], [None], []
+
+    def submit(self, prompt, max_new_tokens):
+        h = types.SimpleNamespace(prompt_ids=[1, 2], out_ids=[], done=False,
+                                  first_token_at=None,
+                                  sent_at=time.perf_counter())
+        self.waiting.append(h)
+        self.sent.append(h)
+        return h
+
+    def step(self):
+        time.sleep(self.dt)
+        h = self.slots[0]
+        if h is None and self.waiting:
+            h = self.slots[0] = self.waiting.pop(0)
+            h.first_token_at = time.perf_counter()
+        if h is not None:
+            h.out_ids.append(0)
+            if len(h.out_ids) >= self.steps:
+                h.done, self.slots[0] = True, None
+
+
+def test_drive_measures_the_window_between_a_lead_in_and_the_rest():
+    """Requests due before the open and after the close load the engine
+    and are not returned; the schedule goes on past the close while the
+    window's requests drain, and the engine is left empty."""
+    mix = dict(traffic.load_mix("decisions"), lead_in_s=0.4,
+               arrivals={"process": "poisson", "rate_per_s": 8.0})
+    tr = traffic.Traffic(mix, 1.0, 5)
+    eng = _SlowEngine()
+    reqs, withdrawn, steps, t_open, t_close = harness.drive(eng, tr, 1.0)
+    assert [r.spec for r in reqs] == tr.specs and withdrawn == 0
+    assert all(r.done for r in reqs)
+    early = [h for h in eng.sent if h.sent_at < t_open]
+    assert len(early) == len(tr.lead_in) > 0
+    late = [r for r in reqs if r.handle.sent_at >= t_close]
+    assert all(r.due < t_close for r in late)
+    rest = eng.sent[len(tr.lead_in) + len(reqs):]
+    assert rest, "the drain outlasts the next arrival at this load"
+    assert all(h.sent_at >= t_close for h in rest)
+    assert not eng.waiting and eng.slots == [None]
+    assert any(not s.in_window for s in steps if s.t0 < t_open)
+    assert all(s.in_window == (t_open <= s.t0 < t_close) for s in steps)

@@ -81,6 +81,21 @@ def test_innermost_prefers_the_shorter_of_two_that_start_together():
     assert pt.innermost(spans, 31 * MS) == "untraced"
 
 
+@pytest.mark.parametrize("frames, want", [
+    (["engine.step", "serving.step", "serving.decode", "a", "b", "c"],
+     ["serving.decode", "a", "b", "c"]),
+    (["engine.step", "serving.step", "serving.decode", "b", "c"],
+     ["serving.decode", "b", "c"]),
+    (["engine.step", "serving.step", "a", "serving.retire", "c"],
+     ["a", "serving.retire", "c"]),
+    (["engine.step", "a", "b", "c"], ["a", "b", "c"]),
+    (["b", "c"], ["b", "c"]),
+], ids=["span_further_out", "span_at_the_edge", "span_inside",
+        "no_span", "short"])
+def test_host_stack_keeps_the_engines_innermost_span(frames, want):
+    assert pt.stack_tail(frames, 3) == want
+
+
 def test_readings_of_spans_and_counters():
     counters = {"serving.steps": 4, "serving.host_reads": 18,
                 "serving.prefill_tokens": 900,
@@ -146,9 +161,9 @@ def test_step_walls_by_slots():
              harness.Step(0.0, 0.200, [9], [5, 6], True, True),
              harness.Step(0.0, 0.030, [], [5, 6, 7], False, True),
              harness.Step(0.0, 0.500, [], [5, 6], False, False)]
-    run = harness.Run(cell="c", model={}, seed=1, seconds=1.0, setup_s=1.0,
-                      t_open=0.0, t_close=1.0, requests=[], withdrawn=0,
-                      steps=steps, compiles_in_window=0,
+    run = harness.Run(cell="c", model={}, work=None, seed=1, seconds=1.0,
+                      setup_s=1.0, t_open=0.0, t_close=1.0, requests=[],
+                      withdrawn=0, steps=steps, compiles_in_window=0,
                       compile_s_in_window=0.0, memory_peak_bytes=0, peaks={})
     got = pt.step_walls(run)
     assert got == {2: [pytest.approx(23.0), pytest.approx(22.0), 1, 2],
@@ -198,10 +213,10 @@ def test_recorded_trace_through_all_nine_readers():
              for st in ev["steps"]]
     cell = harness.find_cell("granite-3-2b.decisions")
     run = harness.Run(
-        cell="x", model=cell.config["model"], seed=0, seconds=1, setup_s=1,
-        t_open=0, t_close=1, requests=[], withdrawn=0, steps=steps,
-        compiles_in_window=0, compile_s_in_window=0, memory_peak_bytes=0,
-        trace=s, peaks=harness.peaks_for("TPU v5 lite"))
+        cell="x", model=cell.config["model"], work=cell.work, seed=0,
+        seconds=1, setup_s=1, t_open=0, t_close=1, requests=[], withdrawn=0,
+        steps=steps, compiles_in_window=0, compile_s_in_window=0,
+        memory_peak_bytes=0, trace=s, peaks=harness.peaks_for("TPU v5 lite"))
     got = {k: v["value"] for k, v in
            harness.read_metrics(run, cell.per_layer).items()}
     # no requests in the record: the host-clock reader reads nothing

@@ -94,10 +94,10 @@ def test_recorded_trace_metrics_stay_under_their_peaks():
     steps = [Step(0, 0, [1800] if i == 3 else [], [2000] * 8, True, True)
              for i in range(7)]
     cell = harness.find_cell("granite-3-2b.decisions")
-    run = Run(cell="x", model=cell.config["model"], seed=0, seconds=1,
-              setup_s=1, t_open=0, t_close=1, requests=[], withdrawn=0,
-              steps=steps, compiles_in_window=0, compile_s_in_window=0,
-              memory_peak_bytes=0, trace=s,
+    run = Run(cell="x", model=cell.config["model"], work=cell.work, seed=0,
+              seconds=1, setup_s=1, t_open=0, t_close=1, requests=[],
+              withdrawn=0, steps=steps, compiles_in_window=0,
+              compile_s_in_window=0, memory_peak_bytes=0, trace=s,
               peaks=harness.peaks_for("TPU v5 lite"))
     got = harness.read_metrics(run, cell.per_layer)
     assert set(got) == {"prefill_mfu_pct", "decode_roofline_pct",

@@ -1,5 +1,6 @@
 """The open-loop schedule and the backlog, from their seeds."""
 import collections
+import itertools
 
 import numpy as np
 import pytest
@@ -55,6 +56,35 @@ def test_seeds_share_the_gaps_and_requests_in_another_order():
     assert [_key(x) for x in a] != [_key(x) for x in b]
     assert collections.Counter(map(_key, a)) == \
         collections.Counter(map(_key, b))
+
+
+@pytest.mark.parametrize("seconds", [50, 5])
+def test_schedule_repeats_around_the_window(seconds):
+    """The lead-in is the schedule's own end a period early (at most one
+    period of it), and past the close the schedule goes on from its start,
+    period after period."""
+    mix = traffic.load_mix("decisions")
+    tr = traffic.Traffic(mix, seconds, SEED)
+    lead = min(mix["lead_in_s"], seconds)
+    assert tr.lead_in_s == lead > 0
+    end = [s for s in tr.specs if s.due_s >= seconds - lead]
+    assert tr.lead_in and len(tr.lead_in) == len(end)
+    for a, b in zip(tr.lead_in, end):
+        assert a.due_s == pytest.approx(b.due_s - seconds)
+        assert _key(a) == _key(b) and a.idx == b.idx
+    assert all(-lead <= s.due_s < 0 for s in tr.lead_in)
+    k = len(tr.specs)
+    after = list(itertools.islice(tr.after(), 2 * k))
+    for j, s in enumerate(after):
+        base = tr.specs[j % k]
+        assert s.due_s == pytest.approx(base.due_s + (1 + j // k) * seconds)
+        assert _key(s) == _key(base)
+
+
+def test_a_closed_loop_has_no_lead_in():
+    tr = traffic.Traffic(traffic.load_mix("cot-backlog"), 50, SEED)
+    assert tr.lead_in_s == 0 and tr.lead_in == []
+    assert list(tr.after()) == []
 
 
 def test_decision_prompts_fill_one_prefill_bucket():

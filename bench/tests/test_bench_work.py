@@ -4,7 +4,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-from bench import work
+from bench import reference, work
 
 # 2 layers, d 8, 2 query heads and 1 key/value head of 4, d_ff 16, vocab 300
 M = {"n_layers": 2, "d_model": 8, "n_heads": 2, "n_kv_heads": 1,
@@ -45,10 +45,9 @@ def test_weight_bytes_match_the_served_tree(arch):
     from repro.models.common import abstract_init
     from repro.models.model import init_model
 
-    from bench.harness import model_dict
     cfg = dataclasses.replace(get_config(arch).reduced(), vocab_size=512)
     params, _ = abstract_init(init_model, cfg)
     n = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
     if not cfg.tie_embeddings:
         n -= int(np.prod(params["embed"].shape))
-    assert work.weight_bytes(model_dict(cfg)) == 2 * n
+    assert work.weight_bytes(reference.model_block(cfg)) == 2 * n

@@ -184,7 +184,8 @@ def host_stacks(trace_dir: str, times: Sequence[float],
                 depth: int = 8) -> List[List[str]]:
     """For each time, the host events open then on the thread that holds
     the most of them (the profiler's Python tracer records every call),
-    outermost first, the innermost ``depth``."""
+    outermost first: the innermost ``depth``, and the engine's innermost
+    span where it lies further out (``stack_tail``)."""
     found: List[Dict] = [{} for _ in times]
     for plane in _profile(trace_dir).planes:
         if not plane.name.startswith("/host:"):
@@ -197,8 +198,19 @@ def host_stacks(trace_dir: str, times: Sequence[float],
                     if a <= t < b:
                         found[i].setdefault(ln.name, []).append(
                             (a, -b, e.name))
-    return [[n for _, _, n in sorted(max(f.values(), key=len))][-depth:]
-            if f else [] for f in found]
+    return [stack_tail([n for _, _, n in sorted(max(f.values(), key=len))],
+                       depth) if f else [] for f in found]
+
+
+def stack_tail(frames: List[str], depth: int) -> List[str]:
+    """The innermost ``depth`` of ``frames`` (outermost first), after the
+    innermost ``serving.*`` span where none is among them: a gap's midpoint
+    often lands deep inside JAX's dispatch, below the engine's span."""
+    kept = frames[-depth:]
+    if not any(n.startswith("serving.") for n in kept):
+        kept = [n for n in frames[:-depth]
+                if n.startswith("serving.")][-1:] + kept
+    return kept
 
 
 def fixture(ev: Dict, steps: List[Dict], counters: Dict[str, float],
@@ -227,14 +239,14 @@ def fixture(ev: Dict, steps: List[Dict], counters: Dict[str, float],
 
 class KeepingTracer(harness._Tracer):
     """The benchmark's tracer, which also keeps the program's spans, the
-    counters at the window's open (it is built just before the window) and
-    close (its stop), what the host was doing in the longest idle gaps, and
-    marks each garbage collection while it records as a ``gc.gen<n>`` span."""
+    counters at the window's open and close (its stop), what the host was
+    doing in the longest idle gaps, and marks each garbage collection while
+    it records as a ``gc.gen<n>`` span."""
     last: Optional["KeepingTracer"] = None
 
     def __init__(self):
         super().__init__()
-        self.at_open = profiling.snapshot()
+        self.at_open: Dict[str, float] = {}
         self.at_close: Dict[str, float] = {}
         self.events: Optional[Dict] = None
         self.stacks: List[List[str]] = []
@@ -250,6 +262,9 @@ class KeepingTracer(harness._Tracer):
         elif self._gc_span is not None:
             self._gc_span.__exit__(None, None, None)
             self._gc_span = None
+
+    def open(self):
+        self.at_open = profiling.snapshot()
 
     def start(self):
         super().start()

@@ -18,6 +18,14 @@ requests come:
 - ``{"process": "backlog"}``: closed, no arrival times. The harness keeps
   at least ``max_batch`` requests waiting, so every decode slot stays full.
 
+An open-loop schedule repeats with the window's length as its period: the
+``lead_in_s`` seconds before the window (at most one period; 0 where the
+mix names none) hold the schedule's own last arrivals a period early, and
+after the close it goes on with its first. Those requests are not
+measured; they give the window's first and last arrivals the same queue
+ahead and behind that the turn gives every other arrival, so a burst
+turned to an edge of the window is not served faster.
+
 ``prompts`` names a JSONL pool in ``bench/mixes`` (rows with ``prompt`` and
 ``reply_bytes``). ``reply_tokens`` is ``{"from": "reply_bytes"}`` (the
 length of the programmatic twin's reply to that prompt) or
@@ -28,6 +36,7 @@ whatever the run's seed.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
 import pathlib
 import random
@@ -140,9 +149,27 @@ class Traffic:
             picked = [(t, pairs[i % len(pairs)]) for t, i in due]
             self.specs = [RequestSpec(j, t, r["prompt"], n, r["kind"])
                           for j, (t, (r, n)) in enumerate(picked)]
+            self.lead_in_s = min(float(mix.get("lead_in_s", 0.0)), seconds)
         else:
             self._cycle = [pairs[j] for j in rng.permutation(len(pairs))]
             self.specs = []
+            self.lead_in_s = 0.0
+        # the schedule's last arrivals, a period before the window
+        self.lead_in = [s for s in self._period(-1)
+                        if s.due_s >= -self.lead_in_s]
+
+    def _period(self, k: int) -> List[RequestSpec]:
+        """The window's requests ``k`` periods later."""
+        return [dataclasses.replace(s, due_s=s.due_s + k * self.seconds)
+                for s in self.specs]
+
+    def after(self) -> Iterator[RequestSpec]:
+        """The schedule past the close, period after period, without end
+        (nothing where the window has no arrivals)."""
+        if not self.specs:
+            return
+        for k in itertools.count(1):
+            yield from self._period(k)
 
     def backlog(self) -> Iterator[RequestSpec]:
         """Backlog requests without end, cycling the seed's order."""

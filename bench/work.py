@@ -4,6 +4,10 @@ slots, the keys each slot really attends to, and the real vocabulary. What a
 build computes or moves on top of that (padding, idle slots, masked ring
 slots, cache copies) is not counted, so the counts hold whatever implements
 the steps.
+
+These are the dense decoder's counts. A configuration file names this
+module under ``"work"``; the metric readers call ``prefill_flops``,
+``decode_flops`` and ``decode_bytes`` through ``Run.work``.
 """
 from __future__ import annotations
 
