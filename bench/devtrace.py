@@ -105,22 +105,34 @@ class Summary:
     op_s: Dict[Tuple[str, str], float]   # (module, op) -> device seconds
     idle_gaps: List[List]
 
-    def role(self, name: str, executions: int) -> Optional[str]:
-        """The module that runs the step ``name`` (``"decode_step"``):
-        ``jit_<name>`` where the program names it, else the module with the
-        most device time among those that ran exactly ``executions`` times
-        (the program's jitted partials all trace as ``jit__unknown``)."""
-        for k in self.module_s:
-            if re.sub(r"\(\d+\)$", "", k) == f"jit_{name}":
-                return k
+    def modules(self, name: str, executions: int) -> List[str]:
+        """The modules that run the step ``name`` (``"decode_step"``):
+        every ``jit_<name>`` where the program names it, one for each shape
+        the step compiled at, else the module with the most device time
+        among those that ran exactly ``executions`` times (the program's
+        jitted partials all trace as ``jit__unknown``)."""
+        named = [k for k in self.module_s if program(k) == f"jit_{name}"]
+        if named:
+            return named
         same = [k for k, n in self.module_n.items() if n == executions]
-        return max(same, key=self.module_s.get) if same else None
+        return [max(same, key=self.module_s.get)] if same else []
 
     def top_ops(self, labels: Dict[str, str], top: int = 10) -> List[List]:
-        """Leaf device operations by total time, as ``module:op``."""
-        ops = sorted(self.op_s.items(), key=lambda kv: -kv[1])[:top]
-        return [[f"{labels.get(m, re.sub(r'[(]\d+[)]$', '', m))}:{o}", v]
-                for (m, o), v in ops]
+        """Leaf device operations by total time, as ``label:op``; an op of
+        every module that shares a label is one entry with their summed
+        time."""
+        op_s: Dict[str, float] = {}
+        for (m, o), v in self.op_s.items():
+            k = f"{labels.get(m, program(m))}:{o}"
+            op_s[k] = op_s.get(k, 0.0) + v
+        return [[k, v] for k, v in
+                sorted(op_s.items(), key=lambda kv: -kv[1])[:top]]
+
+
+def program(module: str) -> str:
+    """A module's name without the fingerprint of the compiled program:
+    ``jit_prefill_step(1234)`` -> ``jit_prefill_step``."""
+    return re.sub(r"\(\d+\)$", "", module)
 
 
 def reduce(ev: Dict, top: int = 10) -> Optional[Summary]:

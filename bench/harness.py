@@ -244,28 +244,32 @@ class Run:
         """True prompt lengths of the prefills in the traced window."""
         return [n for s in self.traced_steps() for n in s.admitted]
 
+    def traced_count(self, step: str) -> int:
+        """Executions of ``step`` the loop saw in the traced window."""
+        return len({"decode_step": self.traced_decodes,
+                    "prefill_step": self.traced_prefills}[step]())
+
     def module_roles(self) -> Dict[str, str]:
-        """Which traced module ran decode_step and which prefill_step,
-        told apart by how often each ran against what the loop saw."""
+        """Which traced modules ran decode_step and which prefill_step: by
+        name, every program a step compiled; else told apart by how often
+        each ran against what the loop saw."""
         if self.trace is None:
             return {}
         roles = {}
-        dec = self.trace.role("decode_step", len(self.traced_decodes()))
-        if dec:
-            roles[dec] = "decode_step"
-        n = len(self.traced_prefills())
-        pre = self.trace.role("prefill_step", n) if n else None
-        if pre and pre != dec:
-            roles[pre] = "prefill_step"
+        for step in ("decode_step", "prefill_step"):
+            for k in self.trace.modules(step, self.traced_count(step)):
+                roles.setdefault(k, step)
         return roles
 
     def device_seconds(self, step: str) -> Optional[float]:
-        """Device time of the traced executions of ``step``, or None where
-        the trace does not show them one for one."""
-        for k, v in self.module_roles().items():
-            if v == step:
-                return self.trace.module_s[k]
-        return None
+        """Device time of the traced executions of ``step``, summed over
+        every module that ran it, or None where those modules' executions
+        are not the loop's one for one."""
+        keys = [k for k, v in self.module_roles().items() if v == step]
+        if not keys or sum(self.trace.module_n[k] for k in keys) \
+                != self.traced_count(step):
+            return None
+        return sum(self.trace.module_s[k] for k in keys)
 
 
 @contextlib.contextmanager

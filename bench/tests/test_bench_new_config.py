@@ -56,19 +56,19 @@ def _checkout(root, leave_out=None):
 def recorded_trace(monkeypatch):
     """The profiler held still and the chip's recorded trace
     (``data/trace_granite_spans.json.gz``) read in place of the CPU's, which
-    holds no device ops for the trace readers."""
+    holds no device ops for the trace readers; returns the record."""
     import jax
     with gzip.open(DATA / "trace_granite_spans.json.gz", "rt") as f:
         ev = json.load(f)
     monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     monkeypatch.setattr(harness.devtrace, "extract", lambda d: ev)
+    return ev
 
 
 @pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
 def test_config_added_with_new_files_only(tmp_path, request, traced):
-    if traced:
-        request.getfixturevalue("recorded_trace")
+    rec = request.getfixturevalue("recorded_trace") if traced else None
     cell, cfg, model = tiny(CELL, bench_json=_checkout(tmp_path))
     # the modules the configuration names, and no file in their place
     for key, mod in (("reference", cell.reference), ("work", cell.work)):
@@ -85,6 +85,11 @@ def test_config_added_with_new_files_only(tmp_path, request, traced):
     run, checks, ctl = harness.run_cell(
         cell, 3, 1.5, traced, t_start=time.perf_counter(), cfg=cfg,
         model=model, peaks=PEAKS, control="fp8")
+    if traced:
+        # the recorded trace is read against the loop's record of its own
+        # steps, which its modules' executions match one for one
+        run.steps = [harness.Step(0, 0, st["admitted"], st["keys"], True,
+                                  True) for st in rec["steps"]]
     line = harness.result_line(cell, run, checks, DEVICE, traced)
     assert line["correct"] is True and line["attempted"] >= 1
     assert run.work is cell.work

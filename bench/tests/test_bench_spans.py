@@ -71,7 +71,7 @@ def test_gaps_take_the_innermost_open_span():
     # the benchmark's own reduction still tags by its own spans alone
     s = devtrace.reduce({k: ev[k] for k in ("planes", "device", "host")})
     assert {g[0] for g in s.idle_gaps} == {"engine.step", "untraced"}
-    assert s.role("decode_step", 5) == "jit_decode_step(3)"
+    assert s.modules("decode_step", 5) == ["jit_decode_step(3)"]
 
 
 def test_innermost_prefers_the_shorter_of_two_that_start_together():
@@ -178,8 +178,8 @@ def _recorded():
 def test_recorded_steps_trace_under_their_names():
     """The jitted steps are found by name, whatever the execution counts."""
     s = devtrace.reduce(_recorded())
-    dec = s.role("decode_step", 99)
-    pre = s.role("prefill_step", 99)
+    [dec] = s.modules("decode_step", 99)
+    [pre] = s.modules("prefill_step", 99)
     assert dec.startswith("jit_decode_step(") and s.module_n[dec] == 7
     assert pre.startswith("jit_prefill_step(") and s.module_n[pre] == 1
     assert not any(k.startswith("jit__unknown") for k in s.module_s)
