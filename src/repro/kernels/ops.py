@@ -23,7 +23,8 @@ def _interpret() -> bool:
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     chunk: Optional[int] = None,
-                    block_q: int = 128, block_k: int = 128):
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None):
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                chunk=chunk, block_q=block_q, block_k=block_k,
                                interpret=_interpret())

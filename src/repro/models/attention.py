@@ -1,11 +1,13 @@
 """Attention for all families: GQA, RoPE, qk-norm, QKV bias, sliding-window,
 chunked-local (llama4/iRoPE-style), cross-attention, ring-buffer decode cache.
 
-Training/prefill attention is *chunked-query*: we scan over query chunks and
-compute (chunk x S) score tiles, so the S x S score matrix is never
-materialised (required for the 32K-token prefill shapes). The Pallas flash
-kernel in ``repro.kernels`` is the TPU hot path; this XLA path is the
-portable reference and what the dry-run lowers.
+Training attention is *chunked-query*: we scan over query chunks and compute
+(chunk x S) score tiles, so the S x S score matrix is never materialised
+(required for the 32K-token prefill shapes). Prefill's causal
+self-attention on a TPU runs the Pallas flash kernel instead
+(``flash_prefill``), whose score blocks stay in VMEM; everywhere else
+(training, cross-attention, the non-causal encoder, the CPU and the dry-run)
+runs the chunk scan.
 """
 from __future__ import annotations
 
@@ -16,6 +18,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
+from repro.kernels import ops as kernel_ops
+from repro.kernels.flash_attention import takes as flash_takes
 from repro.models.common import Init, maybe_scan, rms_norm, rope
 
 NEG_INF = -1e30
@@ -89,6 +93,19 @@ def _pick_chunk(s: int, target: int = 1024) -> int:
     return max(c, 1)
 
 
+def flash_prefill(cfg: ModelConfig, seq_len: int, *, return_kv: bool,
+                  causal: bool = True, cross: bool = False) -> bool:
+    """Whether ``attend`` runs the Pallas flash kernel: in prefill
+    (``return_kv``; the kernel has no backward, so training stays on the
+    chunk scan), for causal self-attention, on a TPU, at a length and head
+    size the kernel takes. Sliding-window and chunked-local masks run inside
+    the kernel. The serving engine counts the admissions that run it by this
+    test."""
+    return (return_kv and causal and not cross and not cfg.attn_free
+            and not kernel_ops._interpret()
+            and flash_takes(seq_len, cfg.head_dim_))
+
+
 def attend(p: Dict, cfg: ModelConfig, x: jax.Array, *,
            causal: bool = True, kv_x: Optional[jax.Array] = None,
            use_rope: bool = True, return_kv: bool = False):
@@ -105,8 +122,31 @@ def attend(p: Dict, cfg: ModelConfig, x: jax.Array, *,
     if use_rope and kv_x is None:
         q = rope(q.reshape(B, S, -1, hd), qpos, cfg.rope_theta).reshape(q.shape)
         k = rope(k, kpos, cfg.rope_theta)
-    scale = hd ** -0.5
+    if flash_prefill(cfg, S, return_kv=return_kv, causal=causal,
+                     cross=kv_x is not None):
+        out = kernel_ops.flash_attention(
+            q.reshape(B, S, -1, hd).transpose(0, 2, 1, 3),
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+            window=cfg.sliding_window, chunk=cfg.attn_chunk)
+        out = out.transpose(0, 2, 1, 3)
+    else:
+        out = _chunk_scan(cfg, q, k, v, qpos, kpos, causal,
+                          local=kv_x is None)
+    out = constrain(out.reshape(B, S, -1), ("batch", "seq", "act_heads"))
+    proj = jnp.einsum("bsh,hd->bsd", out, p["wo"])
+    if return_kv:
+        return proj, (k.reshape(B, Skv, -1), v.reshape(B, Skv, -1))
+    return proj
 
+
+def _chunk_scan(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
+                qpos: jax.Array, kpos: jax.Array, causal: bool,
+                local: bool) -> jax.Array:
+    """Attention as a scan over query chunks, each scoring a (chunk x S)
+    tile. q (B,S,KV,G,hd), k/v (B,Skv,KV,hd) -> (B,S,KV,G,hd)."""
+    B, S = q.shape[:2]
+    Skv = k.shape[1]
+    scale = q.shape[-1] ** -0.5
     c = _pick_chunk(S)
     n = S // c
     qc = q.reshape(B, n, c, *q.shape[2:]).transpose(1, 0, 2, 3, 4, 5)
@@ -118,7 +158,7 @@ def attend(p: Dict, cfg: ModelConfig, x: jax.Array, *,
     # FLOPs that full-row chunked attention pays (EXPERIMENTS §Perf it.1,
     # hymba prefill: 32x fewer attention FLOPs at window=1024, S=32K).
     kv_span = None
-    if causal and kv_x is None and Skv == S:
+    if causal and local and Skv == S:
         if cfg.sliding_window is not None:
             kv_span = min(Skv, cfg.sliding_window - 1 + c)
         elif cfg.attn_chunk is not None and cfg.attn_chunk % c == 0:
@@ -139,18 +179,13 @@ def attend(p: Dict, cfg: ModelConfig, x: jax.Array, *,
             kp = start + jnp.arange(kv_span, dtype=jnp.int32)
         s = jnp.einsum("bckgh,btkh->bkgct", qi, ks,
                        preferred_element_type=jnp.float32) * scale
-        s = s + _mask(qpi, kp, cfg, causal, kv_x is None)[None, None, None]
+        s = s + _mask(qpi, kp, cfg, causal, local)[None, None, None]
         w = jax.nn.softmax(s, axis=-1).astype(vs.dtype)
         o = jnp.einsum("bkgct,btkh->bckgh", w, vs)
         return None, o
 
     _, out = maybe_scan(body, None, (qc, qposc), unroll=cfg.unroll)
-    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, -1)
-    out = constrain(out, ("batch", "seq", "act_heads"))
-    proj = jnp.einsum("bsh,hd->bsd", out, p["wo"])
-    if return_kv:
-        return proj, (k.reshape(B, Skv, -1), v.reshape(B, Skv, -1))
-    return proj
+    return out.transpose(1, 0, 2, 3, 4, 5)
 
 
 def pack_ring(kv: jax.Array, cache_len: int) -> jax.Array:

@@ -18,9 +18,9 @@ While the JAX profiler traces, ``step`` marks its phases as ``serving.*``
 spans (``core/profiling.span``), nested on the calling thread: ``step``
 holds one ``admit`` per admitted request (``prefill``, ``install``,
 ``first_token``), then ``decode``, ``sample`` and ``retire``. Every step adds
-its ``serving.*`` counts (admissions, prefill tokens true and padded,
-blocking device-to-host reads, tokens out, decode batch slots) to
-``profiling.COUNTERS`` in one call.
+its ``serving.*`` counts (admissions, admissions whose prefill runs the flash
+kernel, prefill tokens true and padded, blocking device-to-host reads, tokens
+out, decode batch slots) to ``profiling.COUNTERS`` in one call.
 """
 from __future__ import annotations
 
@@ -37,6 +37,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.configs.shapes import cache_specs, effective_cache_len
 from repro.core import profiling
+from repro.models.attention import flash_prefill
 from repro.models.model import decode_step, prefill_step
 from repro.serving.sampler import sample
 from repro.serving.tokenizer import MIN_VOCAB, ByteTokenizer
@@ -179,6 +180,8 @@ class ServingEngine:
             req.first_token_at = time.perf_counter()
             self.slots[slot] = req
             counts["serving.admissions"] += 1
+            counts["serving.prefill_kernel"] += flash_prefill(
+                self.cfg, bucket, return_kv=True)
             counts["serving.prefill_tokens"] += n
             counts["serving.prefill_padded_tokens"] += bucket
             counts["serving.host_reads"] += 1

@@ -1,9 +1,19 @@
-"""Per-kernel shape/dtype sweeps vs the ref.py oracles (interpret mode)."""
+"""Per-kernel shape/dtype sweeps vs the ref.py oracles (interpret mode), and
+prefill attention through the flash kernel against the XLA chunk scan."""
+import dataclasses
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import get_config
 from repro.kernels import ops, ref
+from repro.kernels.flash_attention import flash_attention as flash_kernel
+from repro.models import Init, init_model, unbox
+from repro.models import attention
+from repro.models.model import prefill_step
 
 RNG = np.random.default_rng(0)
 
@@ -92,3 +102,73 @@ def test_rmsnorm_sweep(shape, dtype):
     gold = ref.ref_rmsnorm(x, g)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(gold, np.float32), **tol(dtype))
+
+
+S_PREFILL = 1024
+GRANITE = get_config("granite-3-2b").reduced()
+
+
+def _prefill(cfg, params, tokens, true_lens):
+    step = jax.jit(functools.partial(prefill_step, cfg, max_len=2 * S_PREFILL))
+    return step(params, {"tokens": tokens}, true_lens=true_lens)
+
+
+@pytest.mark.parametrize("shape", [
+    GRANITE,                                                  # GQA
+    get_config("phi3-mini-3.8b").reduced(),                   # MHA
+    dataclasses.replace(GRANITE, sliding_window=300),
+    dataclasses.replace(GRANITE, attn_chunk=256),
+], ids=["gqa", "mha", "window", "chunk"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_prefill_through_the_kernel_matches_the_chunk_scan(
+        monkeypatch, shape, dtype):
+    """Right-padded rows (``true_lens``) prefilled through attend's kernel
+    path give the chunk scan's K/V cache and last-token logits: in float32
+    to its tolerance; in bfloat16 within twice the chunk scan's own widest
+    gap to the float32 model."""
+    cfg = dataclasses.replace(shape, dtype=dtype)
+    params, _ = unbox(init_model(Init(jax.random.PRNGKey(1),
+                                      dtype=cfg.jnp_dtype), cfg))
+    true_lens = jnp.asarray([S_PREFILL - 24, 301], jnp.int32)
+    tokens = jnp.asarray(RNG.integers(1, cfg.vocab_size, (2, S_PREFILL)),
+                         jnp.int32)
+    tokens = jnp.where(jnp.arange(S_PREFILL)[None] < true_lens[:, None],
+                       tokens, 0)
+    assert not attention.flash_prefill(cfg, S_PREFILL, return_kv=True)
+    want = _prefill(cfg, params, tokens, true_lens)
+    f32 = dataclasses.replace(shape, dtype="float32")
+    exact = _prefill(f32, jax.tree.map(lambda a: a.astype(jnp.float32),
+                                       params), tokens, true_lens)
+    with monkeypatch.context() as m:    # a TPU seen; the kernel interpreted
+        m.setattr(ops, "_interpret", lambda: False)
+        m.setattr(ops, "flash_attention",
+                  functools.partial(flash_kernel, interpret=True))
+        assert attention.flash_prefill(cfg, S_PREFILL, return_kv=True)
+        got = _prefill(cfg, params, tokens, true_lens)
+    assert np.array_equal(got[0]["pos"], want[0]["pos"])
+    for g, w, e in [(got[0][k], want[0][k], exact[0][k]) for k in "kv"] + [
+            (got[1], want[1], exact[1])]:
+        g, w, e = (np.asarray(a, np.float32) for a in (g, w, e))
+        if dtype == "float32":
+            np.testing.assert_allclose(g, w, **tol(jnp.float32))
+        else:
+            assert np.abs(g - e).max() <= 2 * np.abs(w - e).max()
+
+
+@pytest.mark.parametrize("tpu,cfg,seq_len,kw,kernel", [
+    (True, GRANITE, 2048, dict(return_kv=True), True),
+    (True, GRANITE, 2048, dict(return_kv=False), False),       # training
+    (True, GRANITE, 2048, dict(return_kv=True, cross=True), False),
+    (True, GRANITE, 2048, dict(return_kv=True, causal=False), False),
+    (True, GRANITE, 2000, dict(return_kv=True), False),        # S % 128
+    (True, dataclasses.replace(GRANITE, head_dim=320), 2048,
+     dict(return_kv=True), False),
+    (True, get_config("rwkv6-7b").reduced(), 2048, dict(return_kv=True),
+     False),                                                   # no attention
+    (False, GRANITE, 2048, dict(return_kv=True), False),       # the CPU
+], ids=["prefill", "train", "cross", "non-causal", "seq-len", "head-dim",
+        "attention-free", "cpu"])
+def test_flash_prefill_dispatch(monkeypatch, tpu, cfg, seq_len, kw, kernel):
+    if tpu:
+        monkeypatch.setattr(ops, "_interpret", lambda: False)
+    assert attention.flash_prefill(cfg, seq_len, **kw) is kernel

@@ -3,6 +3,7 @@ the JAX profiler's own trace, nested as the engine's phases nest; counters
 that count each blocking device-to-host read; backend compiles counted by
 one listener; ``stats()`` read from the counters and the request stamps."""
 import dataclasses
+import functools
 import glob
 import os
 
@@ -12,6 +13,8 @@ import pytest
 
 from repro.configs import get_config
 from repro.core import profiling
+from repro.kernels import ops
+from repro.kernels.flash_attention import flash_attention
 from repro.models import Init, init_model, unbox
 from repro.serving import ServingEngine
 
@@ -129,6 +132,31 @@ def test_host_reads_count_each_blocking_read(model):
     # prompts of 6, 32 (cut to max_len // 2) and 4 tokens: buckets 8, 32, 8
     assert [len(r.prompt_ids) for r in reqs] == [6, 32, 4]
     assert got["serving.prefill_padded_tokens"] == 8 + 32 + 8
+
+
+@pytest.mark.parametrize("tpu,kernel", [(False, 0), (True, 1)],
+                         ids=["cpu", "tpu"])
+def test_prefill_kernel_counts_the_admissions_that_run_it(
+        model, monkeypatch, tpu, kernel):
+    """``serving.prefill_kernel`` counts an admission by the test that
+    dispatches its prefill: none on the CPU; on a TPU, the one whose bucket
+    (128) the kernel takes and not the one of 8 (seen as a TPU here, with
+    the kernel interpreted)."""
+    if tpu:
+        monkeypatch.setattr(ops, "_interpret", lambda: False)
+        monkeypatch.setattr(ops, "flash_attention", functools.partial(
+            flash_attention, interpret=True))
+    cfg, params = model
+    eng = ServingEngine(cfg, params, max_batch=2, max_len=256)
+    reqs = [eng.submit(p, max_new_tokens=2) for p in ("x" * 100, "geo")]
+    before = profiling.snapshot()
+    eng.run_until_done()
+    got = profiling.delta(before, profiling.snapshot())
+    assert got["serving.admissions"] == len(reqs)
+    assert got.get("serving.prefill_kernel", 0) == kernel
+    assert eng.counters["serving.prefill_kernel"] == kernel
+    assert "serving.prefill_kernel" in eng.counters
+    assert all(r.done for r in reqs)
 
 
 def test_compiles_are_counted_by_one_listener():

@@ -1,8 +1,8 @@
 """Compile for a described TPU v5e chip, with no chip attached.
 
 Each Pallas kernel at the widths ``chip_smoke.py`` runs, the full-width
-dcache-agent-150m serving steps, and granite-3-2b's decode step at the
-benchmark's size, go through the TPU compiler here, so what the chip's
+dcache-agent-150m serving steps, and granite-3-2b's decode and prefill steps
+at the benchmark's size, go through the TPU compiler here, so what the chip's
 compiler refuses fails this file instead of a chip run. Nothing executes:
 arguments are shapes only.
 """
@@ -18,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.configs.shapes import cache_specs
+from repro.kernels import ops
 from repro.launch.serve import PRESETS
 from repro.models.common import Init, unbox
 from repro.models.model import decode_step, init_model, prefill_step
@@ -83,6 +84,19 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("head_dim", [64, 96, 128, 256])
+def test_flash_attention_compiles_at_the_head_sizes_it_takes(one_chip,
+                                                             head_dim):
+    """The default blocks of a 2048-token GQA prefill (32 query heads over 8)
+    fit the kernel's VMEM at every head size ``takes`` admits."""
+    from repro.kernels.flash_attention import MAX_HEAD_DIM, flash_attention
+    assert head_dim <= MAX_HEAD_DIM
+    q = _spec(one_chip, (1, 32, SEQ, head_dim))
+    kv = _spec(one_chip, (1, 8, SEQ, head_dim))
+    compiled = flash_attention.lower(q, kv, kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_wkv_compiles_for_bf16_inputs(one_chip):
     h, hd = WKV_CFG.n_ssm_heads, WKV_CFG.ssm.head_dim
     x = _spec(one_chip, (1, h, SEQ // 8, hd))
@@ -141,3 +155,28 @@ def test_donated_decode_writes_the_ring_in_place(one_chip, kv_quant):
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= sum(nbytes.values())
     assert m.temp_size_in_bytes < nbytes["k"], m.temp_size_in_bytes
+
+
+def test_prefill_scores_stay_out_of_hbm(one_chip, monkeypatch):
+    """granite-3-2b's prefill at the benchmark's 2048 bucket and 4096
+    positions runs its attention in the flash kernel, and needs at least
+    one f32 score tile of the 1024-query chunk scan (1 x 8 x 4 x 1024 x
+    2048 x 4 B) less scratch than the chunk scan does: no score tile goes
+    to HBM. (Both keep a relayout of ``wq`` and ``wk``, 420 MB, in their
+    scratch.) The dispatch asks the backend, which is the CPU here, so the
+    test tells it when a TPU runs."""
+    def prefill():
+        step = jax.jit(functools.partial(prefill_step, GRANITE, max_len=4096))
+        return step.lower(
+            _params(one_chip, GRANITE),
+            {"tokens": _spec(one_chip, (1, SEQ), jnp.int32)},
+            true_lens=_spec(one_chip, (1,), jnp.int32)).compile()
+
+    scan = prefill()
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    kernel = prefill()
+    assert "tpu_custom_call" not in scan.as_text()
+    assert "tpu_custom_call" in kernel.as_text()
+    score_tile = 1 * 8 * 4 * 1024 * SEQ * 4
+    assert kernel.memory_analysis().temp_size_in_bytes <= \
+        scan.memory_analysis().temp_size_in_bytes - score_tile
